@@ -27,7 +27,7 @@ import jax
 
 from ..dist.sharding import ShardingConfig
 from ..launch import policies, shapes, steps
-from ..launch.mesh import make_production_mesh, set_mesh
+from ..launch.mesh import make_production_mesh
 from ..models.config import ArchConfig
 from ..roofline import analysis
 from ..roofline.hlo import collective_census
@@ -119,7 +119,7 @@ def evaluate_config(arch_cfg: ArchConfig, cell: shapes.ShapeCell,
     else:
         t0 = time.time()
         mesh = make_production_mesh(shape=(d, m), axes=("data", "model"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if cell.kind == "train":
                 bundle = steps.make_train_step(
                     cfg, scfg, mesh, policies.default_opt(cfg),

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 __all__ = [
     "CompressionConfig", "compress_with_feedback", "init_error_state",
     "quantize_int8", "dequantize_int8", "topk_compress", "topk_decompress",
@@ -150,7 +148,8 @@ def compressed_allreduce_mean(x: jax.Array, mesh, axis: str,
         return jax.lax.psum(contrib, axis) / size
 
     spec = P(axis, *([None] * (x.ndim - 1)))
-    return shard_map(local, mesh, in_specs=(spec,), out_specs=spec)(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(x)
 
 
 # -- wire accounting ------------------------------------------------------------

@@ -69,7 +69,7 @@ def grid_compiler_params(dims: str, n_parallel: int, n_carry: int):
         raise ValueError(f"dims must be 'parallel' or 'arbitrary', "
                          f"got {dims!r}")
     semantics = (dims,) * n_parallel + ("arbitrary",) * n_carry
-    return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def resolve_launch_params(kernel: str, meta: Mapping[str, Any], dtype: Any,

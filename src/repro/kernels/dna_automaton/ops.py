@@ -83,8 +83,8 @@ def fa_match(text: jax.Array, table: jax.Array, accept: jax.Array, *,
                                    else chunk),
                    "dims": dims},
         tuned=tuned)
-    mc = largest_aligned_divisor(t, p["map_chunk"])
-    cc = largest_aligned_divisor(t, p["count_chunk"])
+    mc = largest_aligned_divisor(t, p["map_chunk"], align=128)
+    cc = largest_aligned_divisor(t, p["count_chunk"], align=128)
     if cc % mc:
         cc = mc
     maps = state_map_kernel(text, table, chunk=mc,

@@ -13,6 +13,10 @@ Backward:
 
 The backward uses the saved forward logsumexp (L = m + log l) and
 delta = rowsum(do * o), the standard FA-2 decomposition.
+
+Row statistics (m, l, L, delta) are (rows, 1) columns, in VMEM and in
+HBM alike: a trailing unit dim keeps every block on the TPU's (8, 128)
+tiling, which a (1, block_q) row of a (B*H, T) array would break.
 """
 
 from __future__ import annotations
@@ -53,26 +57,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     if causal:
         s = jnp.where(_mask(iq, ik, q.shape[0], k.shape[0], q_offset),
                       s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    m_prev = m_ref[...]                               # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
     v = v_ref[0].astype(jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(p, v)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
     m_ref[...] = m_new
 
     @pl.when(ik == n_k - 1)
     def _final():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, q_offset: int = 0,
                         block_q: int = 128, block_k: int = 128,
                         dims: str = "parallel", interpret: bool = False):
-    """q/k/v: (BH, T, hd) with kv already head-repeated. Returns (o, lse)."""
+    """q/k/v: (BH, T, hd) with kv already head-repeated.
+
+    Returns (o, lse) with lse of shape (BH, T, 1).
+    """
     bh, tq, hd = q.shape
     tk = k.shape[1]
     block_q = largest_aligned_divisor(tq, block_q, align=8)
@@ -91,16 +98,16 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, q_offset: int = 0,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, tq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
@@ -123,11 +130,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     if causal:
         s = jnp.where(_mask(iq, ik, q.shape[0], k.shape[0], q_offset),
                       s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])                   # (bq, bk)
+    p = jnp.exp(s - lse_ref[0])                            # (bq, bk)
     do = do_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     acc_ref[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(ik == n_k - 1)
@@ -151,12 +158,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if causal:
         s = jnp.where(_mask(iq, ik, q.shape[0], k.shape[0], q_offset),
                       s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])
+    p = jnp.exp(s - lse_ref[0])
     do = do_ref[0].astype(jnp.float32)
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
     v = v_ref[0].astype(jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
 
     @pl.when(iq == n_q - 1)
@@ -175,7 +182,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
     block_k = largest_aligned_divisor(tk, block_k, align=8)
     n_q, n_k = tq // block_q, tk // block_k
     scale = hd ** -0.5
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)                        # (BH, T, 1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -186,8 +194,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -205,8 +213,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, hd), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
+            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),

@@ -23,12 +23,15 @@ Forward — two grid programs behind one entry point:
 
 Backward (``selective_scan_bwd``) is recompute-based: a light spans
 pre-pass re-derives the state at every span boundary, then a reverse
-grid sweep (span index map ``n-1-j``) calls ``jax.vjp`` on the pure
-local forward of each span with the incoming output/state cotangents —
-the input cotangents land in per-cell partial outputs (summed by the
-caller for the reduced operands a/b/c/d) and the span-entry cotangent
-becomes the carried adjoint for the previous span.  Residual memory is
-O(inputs): nothing from the forward pass is saved but the inputs.
+grid sweep (span index map ``n-1-j``) recomputes each span's states
+into a (chunk, bd, S) VMEM stack and runs the hand-derived adjoint
+recurrence over them token by token, last token first.  The input
+cotangents land in per-cell partial outputs (summed by the caller for
+the reduced operands a/b/c/d) and the span-entry cotangent becomes the
+carried adjoint for the previous span.  Residual memory is O(inputs):
+nothing from the forward pass is saved but the inputs.  (The loops are
+explicit because Mosaic lowers no ``lax.scan`` with stacked operands,
+which ``jax.vjp`` of a scan would need.)
 """
 
 from __future__ import annotations
@@ -52,7 +55,7 @@ def _serial_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
         h_ref[...] = h0_ref[0]
 
     a = a_ref[...]                                # (bd, S)
-    d = d_ref[...]                                # (bd,)
+    d = d_ref[0]                                  # (bd,)
 
     def step(t, _):
         x_t = x_ref[0, t]                         # (bd,)
@@ -120,14 +123,14 @@ def _chunked_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
 
     # fixup every span token at once: h_t = Hl_t + P_t * h_chunk_start
     big = hl_scr[...] + p_scr[...] * hs[:, None]
-    y = (big * cs[:, :, None, :]).sum(-1) + d_ref[...] * xs
+    y = (big * cs[:, :, None, :]).sum(-1) + d_ref[0] * xs
     y_ref[0] = y.reshape(lanes * chunk, bd)
 
 
 def _clamp_chunking(t: int, chunk: int, lanes: int) -> tuple[int, int]:
     """Clamp (chunk, lanes) so ``chunk * lanes`` divides ``t``; lanes < 2
     collapses to the serial path (the ``lanes=0`` sentinel)."""
-    chunk = largest_aligned_divisor(t, chunk)
+    chunk = largest_aligned_divisor(t, chunk, align=8)
     if lanes >= 2:
         lanes = largest_aligned_divisor(t // chunk, lanes)
     return chunk, (lanes if lanes >= 2 else 0)
@@ -145,7 +148,7 @@ def selective_scan_kernel(x, delta, a, b, c, d, h0, *, block_d: int = 256,
     """
     bt, t, di = x.shape
     s = a.shape[1]
-    block_d = largest_aligned_divisor(di, block_d, align=8)
+    block_d = largest_aligned_divisor(di, block_d, align=128)
     chunk, lanes = _clamp_chunking(t, chunk, lanes)
     span = chunk * lanes if lanes else chunk
     n_spans = t // span
@@ -170,7 +173,7 @@ def selective_scan_kernel(x, delta, a, b, c, d, h0, *, block_d: int = 256,
             xspec, xspec,
             pl.BlockSpec((block_d, s), lambda b_, i, j: (i, 0)),
             sspec, sspec,
-            pl.BlockSpec((block_d,), lambda b_, i, j: (i,)),
+            pl.BlockSpec((1, block_d), lambda b_, i, j: (0, i)),
             hspec,
         ],
         out_specs=[xspec, hspec],
@@ -181,10 +184,10 @@ def selective_scan_kernel(x, delta, a, b, c, d, h0, *, block_d: int = 256,
         scratch_shapes=scratch,
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
-    )(x, delta, a, b, c, d, h0)
+    )(x, delta, a, b, c, d.reshape(1, di), h0)
 
 
-# -- backward: spans pre-pass + reverse vjp sweep -------------------------------
+# -- backward: spans pre-pass + reverse adjoint sweep ---------------------------
 
 def _spans_kernel(x_ref, dt_ref, a_ref, b_ref, h0_ref, hs_ref, h_scr,
                   *, span):
@@ -207,43 +210,57 @@ def _spans_kernel(x_ref, dt_ref, a_ref, b_ref, h0_ref, hs_ref, h_scr,
     jax.lax.fori_loop(0, span, step, ())
 
 
-def _local_scan(x, dt, a, b, c, d, h_in):
-    """Pure forward over one span from its entry state — the function the
-    backward cell differentiates (recompute-in-backward)."""
-    def step(h, inp):
-        x_t, dt_t, b_t, c_t = inp
-        da = jnp.exp(dt_t[:, None] * a)
-        h = da * h + (dt_t * x_t)[:, None] * b_t[None, :]
-        y = (h * c_t[None, :]).sum(axis=1) + d * x_t
-        return h, y
-
-    h_out, y = jax.lax.scan(step, h_in, (x, dt, b, c))
-    return y, h_out
-
-
 def _scan_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, hs_ref,
                      dy_ref, dhT_ref, dx_ref, ddt_ref, da_ref, db_ref,
-                     dc_ref, dd_ref, dh0_ref, g_scr, *, n_spans):
+                     dc_ref, dd_ref, dh0_ref, g_scr, h_scr, *, chunk,
+                     n_spans):
     jr = pl.program_id(2)                         # 0 = last span (reversed)
 
     @pl.when(jr == 0)
     def _init():
         g_scr[...] = dhT_ref[0]
 
-    _, vjp = jax.vjp(_local_scan, x_ref[0], dt_ref[0], a_ref[...],
-                     b_ref[0], c_ref[0], d_ref[...], hs_ref[0, 0])
-    dx, ddt, da_p, db_p, dc_p, dd_p, dh_in = vjp((dy_ref[0], g_scr[...]))
-    dx_ref[0] = dx
-    ddt_ref[0] = ddt
+    a = a_ref[...]                                # (bd, S)
+    d = d_ref[0]                                  # (bd,)
+
+    # recompute the span from its entry state: h_scr[t] = state before t
+    def forward(t, h):
+        h_scr[t] = h
+        dt_t = dt_ref[0, t]
+        return (jnp.exp(dt_t[:, None] * a) * h
+                + (dt_t * x_ref[0, t])[:, None] * b_ref[0, t][None, :])
+
+    jax.lax.fori_loop(0, chunk, forward, hs_ref[0, 0])
+
+    # adjoint of h_t = exp(dt_t a) h_{t-1} + dt_t x_t b_t,
+    # y_t = h_t . c_t + d x_t, token by token in reverse; g = dL/dh_t
+    def backward(i, carry):
+        g, da_acc, dd_acc = carry
+        t = chunk - 1 - i
+        x_t, dt_t, dy_t = x_ref[0, t], dt_ref[0, t], dy_ref[0, t]
+        b_t, c_t = b_ref[0, t], c_ref[0, t]
+        h_prev = h_scr[t]
+        decay = jnp.exp(dt_t[:, None] * a)
+        h_t = decay * h_prev + (dt_t * x_t)[:, None] * b_t[None, :]
+        g = g + dy_t[:, None] * c_t[None, :]
+        gb = (g * b_t[None, :]).sum(axis=1)
+        gh = g * decay * h_prev
+        dc_ref[0, 0, t] = (dy_t[:, None] * h_t).sum(axis=0)
+        db_ref[0, 0, t] = (g * (dt_t * x_t)[:, None]).sum(axis=0)
+        dx_ref[0, t] = dy_t * d + dt_t * gb
+        ddt_ref[0, t] = (gh * a).sum(axis=1) + x_t * gb
+        return (g * decay, da_acc + gh * dt_t[:, None], dd_acc + dy_t * x_t)
+
+    g, da_p, dd_p = jax.lax.fori_loop(
+        0, chunk, backward,
+        (g_scr[...], jnp.zeros_like(a), jnp.zeros_like(d)))
     da_ref[0, 0] = da_p                           # per-cell partials: the
-    db_ref[0, 0] = db_p                           # reduced operands are
-    dc_ref[0, 0] = dc_p                           # summed by the caller
-    dd_ref[0, 0] = dd_p
-    g_scr[...] = dh_in
+    dd_ref[0, 0, 0] = dd_p                        # reduced operands are
+    g_scr[...] = g                                # summed by the caller
 
     @pl.when(jr == n_spans - 1)
     def _final():
-        dh0_ref[0] = dh_in
+        dh0_ref[0] = g
 
 
 def selective_scan_bwd(x, delta, a, b, c, d, h0, dy, dhT, *,
@@ -253,12 +270,12 @@ def selective_scan_bwd(x, delta, a, b, c, d, h0, dy, dhT, *,
     every forward operand.  Returns (dx, ddelta, da, db, dc, dd, dh0)."""
     bt, t, di = x.shape
     s = a.shape[1]
-    block_d = largest_aligned_divisor(di, block_d, align=8)
-    chunk = largest_aligned_divisor(t, chunk)
+    block_d = largest_aligned_divisor(di, block_d, align=128)
+    chunk = largest_aligned_divisor(t, chunk, align=8)
     n_spans = t // chunk
     n_db = di // block_d
     aspec = pl.BlockSpec((block_d, s), lambda b_, i, j: (i, 0))
-    dspec = pl.BlockSpec((block_d,), lambda b_, i, j: (i,))
+    dspec = pl.BlockSpec((1, block_d), lambda b_, i, j: (0, i))
 
     spans = pl.pallas_call(
         functools.partial(_spans_kernel, span=chunk),
@@ -283,7 +300,7 @@ def selective_scan_bwd(x, delta, a, b, c, d, h0, dy, dhT, *,
     sspec_r = pl.BlockSpec((1, chunk, s),
                            lambda b_, i, j: (b_, n_spans - 1 - j, 0))
     out = pl.pallas_call(
-        functools.partial(_scan_bwd_kernel, n_spans=n_spans),
+        functools.partial(_scan_bwd_kernel, chunk=chunk, n_spans=n_spans),
         grid=(bt, n_db, n_spans),
         in_specs=[
             xspec_r, xspec_r, aspec, sspec_r, sspec_r, dspec,
@@ -300,8 +317,8 @@ def selective_scan_bwd(x, delta, a, b, c, d, h0, dy, dhT, *,
                          lambda b_, i, j: (i, b_, n_spans - 1 - j, 0)),
             pl.BlockSpec((1, 1, chunk, s),
                          lambda b_, i, j: (i, b_, n_spans - 1 - j, 0)),
-            pl.BlockSpec((1, 1, block_d),
-                         lambda b_, i, j: (b_, n_spans - 1 - j, i)),
+            pl.BlockSpec((1, 1, 1, block_d),
+                         lambda b_, i, j: (b_, n_spans - 1 - j, 0, i)),
             pl.BlockSpec((1, block_d, s), lambda b_, i, j: (b_, i, 0)),
         ],
         out_shape=[
@@ -310,13 +327,14 @@ def selective_scan_bwd(x, delta, a, b, c, d, h0, dy, dhT, *,
             jax.ShapeDtypeStruct((bt, n_spans, di, s), jnp.float32),
             jax.ShapeDtypeStruct((n_db, bt, t, s), jnp.float32),
             jax.ShapeDtypeStruct((n_db, bt, t, s), jnp.float32),
-            jax.ShapeDtypeStruct((bt, n_spans, di), jnp.float32),
+            jax.ShapeDtypeStruct((bt, n_spans, 1, di), jnp.float32),
             jax.ShapeDtypeStruct((bt, di, s), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_d, s), jnp.float32),
+                        pltpu.VMEM((chunk, block_d, s), jnp.float32)],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
-    )(x, delta, a, b, c, d, spans, dy, dhT)
+    )(x, delta, a, b, c, d.reshape(1, di), spans, dy, dhT)
     dx, ddt, da_p, db_p, dc_p, dd_p, dh0 = out
     return (dx, ddt, da_p.sum(axis=(0, 1)), db_p.sum(axis=0),
-            dc_p.sum(axis=0), dd_p.sum(axis=(0, 1)), dh0)
+            dc_p.sum(axis=0), dd_p.sum(axis=(0, 1, 2)), dh0)

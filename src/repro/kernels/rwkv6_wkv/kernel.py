@@ -25,16 +25,24 @@ read of r/k/v/w and one write of y):
     serial path).
 
 Backward (``wkv6_bwd``) is recompute-based: a spans pre-pass re-derives
-the state at every span boundary, then a reverse grid sweep calls
-``jax.vjp`` on the pure local recurrence of each span (loop form —
-decays are only ever multiplied, so it is unconditionally stable) with
-the incoming output/state cotangents; per-cell partials for the shared
-``u`` are summed by the caller and the span-entry cotangent becomes the
-carried adjoint.  Residual memory is O(inputs).
+the state at every span boundary, then a reverse grid sweep recomputes
+each span's states into a (chunk, bh, hd, hd) VMEM stack (loop form —
+decays are only ever multiplied, so it is unconditionally stable) and
+runs the hand-derived adjoint recurrence over them, last token first;
+per-cell partials for the shared ``u`` are summed by the caller and the
+span-entry cotangent becomes the carried adjoint.  Residual memory is
+O(inputs).  (The loops are explicit because Mosaic lowers no
+``lax.scan`` with stacked operands, which ``jax.vjp`` of a scan would
+need.)
 
 State is read out per cell into ``s_out`` so callers can both resume
 (decode) and checkpoint the recurrence (matching the chunked-remat
 training layout in models/rwkv6.py).
+
+TPU tiling: the entry points take (B, T, H, hd) and run the grid on a
+head-major (B, H, T, hd) copy, so every sequence block is a
+(span, hd) tile rather than a (block_h, hd) slice of the head axis,
+which the (8, 128) tiling rule refuses for ``block_h`` < H.
 """
 
 from __future__ import annotations
@@ -57,17 +65,17 @@ def _serial_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref,
     def _init():
         s_ref[...] = s0_ref[0]
 
-    u = u_ref[...]                                 # (bh, hd)
+    u = u_ref[:, 0]                                # (bh, hd)
 
     def step(t, _):
-        r_t = r_ref[0, t]                          # (bh, hd)
-        k_t = k_ref[0, t]
-        v_t = v_ref[0, t]
-        w_t = w_ref[0, t]
+        r_t = r_ref[0, :, t]                       # (bh, hd)
+        k_t = k_ref[0, :, t]
+        v_t = v_ref[0, :, t]
+        w_t = w_ref[0, :, t]
         s = s_ref[...]                             # (bh, hd, hd) key x value
         kv = k_t[..., :, None] * v_t[..., None, :]
         y = ((s + u[..., :, None] * kv) * r_t[..., :, None]).sum(axis=-2)
-        y_ref[0, t] = y.astype(y_ref.dtype)
+        y_ref[0, :, t] = y.astype(y_ref.dtype)
         s_ref[...] = w_t[..., :, None] * s + kv
         return ()
 
@@ -86,12 +94,12 @@ def _chunked_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref,
     def _init():
         s_scr[...] = s0_ref[0]
 
-    hd = u_ref.shape[1]
-    u = u_ref[...]                                   # (bh, hd)
-    rs = r_ref[0].reshape(lanes, chunk, block_h, hd)
-    ks = k_ref[0].reshape(lanes, chunk, block_h, hd)
-    vs = v_ref[0].reshape(lanes, chunk, block_h, hd)
-    ws = w_ref[0].reshape(lanes, chunk, block_h, hd)
+    hd = u_ref.shape[2]
+    u = u_ref[:, 0]                                  # (bh, hd)
+    def split(ref):                                  # -> (lanes, L, bh, hd)
+        return ref[0].reshape(block_h, lanes, chunk, hd).transpose(1, 2, 0, 3)
+
+    rs, ks, vs, ws = split(r_ref), split(k_ref), split(v_ref), split(w_ref)
 
     logw = jnp.log(ws)
     tril = jnp.tril(jnp.ones((chunk, chunk), jnp.float32))
@@ -123,11 +131,15 @@ def _chunked_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref,
 
     y_inter = jnp.einsum("ltbd,lbdj->ltbj", aa, s_start)
     y = y_intra + y_inter + bonus
-    y_ref[0] = y.reshape(lanes * chunk, block_h, hd)
+    y_ref[0] = y.transpose(2, 0, 1, 3).reshape(block_h, lanes * chunk, hd)
+
+
+def _heads_major(x):  # (B, T, H, hd) <-> (B, H, T, hd)
+    return x.transpose(0, 2, 1, 3)
 
 
 def _clamp_chunking(t: int, chunk: int, lanes: int) -> tuple[int, int]:
-    chunk = largest_aligned_divisor(t, chunk)
+    chunk = largest_aligned_divisor(t, chunk, align=8)
     if lanes >= 2:
         lanes = largest_aligned_divisor(t // chunk, lanes)
     return chunk, (lanes if lanes >= 2 else 0)
@@ -147,8 +159,8 @@ def wkv6_kernel(r, k, v, w, u, s0, *, chunk: int = 64, lanes: int = 0,
     chunk, lanes = _clamp_chunking(t, chunk, lanes)
     span = chunk * lanes if lanes else chunk
     n_spans = t // span
-    seq_spec = pl.BlockSpec((1, span, block_h, hd),
-                            lambda b_, h_, j: (b_, j, h_, 0))
+    seq_spec = pl.BlockSpec((1, block_h, span, hd),
+                            lambda b_, h_, j: (b_, h_, j, 0))
     sspec = pl.BlockSpec((1, block_h, hd, hd),
                          lambda b_, h_, j: (b_, h_, 0, 0))
     if lanes:
@@ -157,26 +169,28 @@ def wkv6_kernel(r, k, v, w, u, s0, *, chunk: int = 64, lanes: int = 0,
     else:
         kernel = functools.partial(_serial_kernel, chunk=chunk,
                                    n_chunks=n_spans)
-    return pl.pallas_call(
+    y, s_t = pl.pallas_call(
         kernel,
         grid=(b, h // block_h, n_spans),
         in_specs=[
             seq_spec, seq_spec, seq_spec, seq_spec,
-            pl.BlockSpec((block_h, hd), lambda b_, h_, j: (h_, 0)),
+            pl.BlockSpec((block_h, 1, hd), lambda b_, h_, j: (h_, 0, 0)),
             sspec,
         ],
         out_specs=[seq_spec, sspec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_h, hd, hd), jnp.float32)],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(_heads_major(r), _heads_major(k), _heads_major(v), _heads_major(w),
+      u.reshape(h, 1, hd), s0)
+    return _heads_major(y), s_t
 
 
-# -- backward: spans pre-pass + reverse vjp sweep -------------------------------
+# -- backward: spans pre-pass + reverse adjoint sweep ---------------------------
 
 def _spans_kernel(k_ref, v_ref, w_ref, s0_ref, ss_ref, s_scr, *, span):
     j = pl.program_id(2)
@@ -188,9 +202,9 @@ def _spans_kernel(k_ref, v_ref, w_ref, s0_ref, ss_ref, s_scr, *, span):
     ss_ref[0, 0] = s_scr[...]                     # state entering this span
 
     def step(t, _):
-        k_t = k_ref[0, t]
-        v_t = v_ref[0, t]
-        w_t = w_ref[0, t]
+        k_t = k_ref[0, :, t]
+        v_t = v_ref[0, :, t]
+        w_t = w_ref[0, :, t]
         kv = k_t[..., :, None] * v_t[..., None, :]
         s_scr[...] = w_t[..., :, None] * s_scr[...] + kv
         return ()
@@ -198,43 +212,51 @@ def _spans_kernel(k_ref, v_ref, w_ref, s0_ref, ss_ref, s_scr, *, span):
     jax.lax.fori_loop(0, span, step, ())
 
 
-def _local_wkv(r, k, v, w, u, s_in):
-    """Pure forward over one span from its entry state — the function the
-    backward cell differentiates (recompute-in-backward).  Loop form:
-    decays are only multiplied, never inverted, so it is stable for any
-    ``w`` in (0, 1)."""
-    def step(s, inp):
-        r_t, k_t, v_t, w_t = inp
-        kv = k_t[..., :, None] * v_t[..., None, :]
-        y = ((s + u[..., :, None] * kv) * r_t[..., :, None]).sum(axis=-2)
-        return w_t[..., :, None] * s + kv, y
-
-    s_out, y = jax.lax.scan(step, s_in, (r, k, v, w))
-    return y, s_out
-
-
 def _wkv_bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, ss_ref, dy_ref,
                     dsT_ref, dr_ref, dk_ref, dv_ref, dw_ref, du_ref,
-                    ds0_ref, g_scr, *, n_spans):
+                    ds0_ref, g_scr, s_scr, *, chunk, n_spans):
     jr = pl.program_id(2)                         # 0 = last span (reversed)
 
     @pl.when(jr == 0)
     def _init():
         g_scr[...] = dsT_ref[0]
 
-    _, vjp = jax.vjp(_local_wkv, r_ref[0], k_ref[0], v_ref[0], w_ref[0],
-                     u_ref[...], ss_ref[0, 0])
-    dr, dk, dv, dw, du_p, ds_in = vjp((dy_ref[0], g_scr[...]))
-    dr_ref[0] = dr
-    dk_ref[0] = dk
-    dv_ref[0] = dv
-    dw_ref[0] = dw
-    du_ref[0, 0] = du_p                           # per-cell partial: summed
-    g_scr[...] = ds_in                            # by the caller
+    u = u_ref[:, 0]                               # (bh, hd)
+
+    # recompute the span from its entry state: s_scr[t] = state before t
+    def forward(t, st):
+        s_scr[t] = st
+        kv = k_ref[0, :, t][..., :, None] * v_ref[0, :, t][..., None, :]
+        return w_ref[0, :, t][..., :, None] * st + kv
+
+    jax.lax.fori_loop(0, chunk, forward, ss_ref[0, 0])
+
+    # adjoint of y_t = r_t (S + u k_t v_t^T), S' = w_t S + k_t v_t^T,
+    # token by token in reverse; g = dL/dS' (the state after token t)
+    def backward(i, carry):
+        g, du_acc = carry
+        t = chunk - 1 - i
+        r_t, k_t, v_t, w_t, dy_t = (ref[0, :, t] for ref in
+                                    (r_ref, k_ref, v_ref, w_ref, dy_ref))
+        st = s_scr[t]                             # (bh, hd, hd) key x value
+        kv = k_t[..., :, None] * v_t[..., None, :]
+        ry = r_t[..., :, None] * dy_t[..., None, :]          # dL/dS via y_t
+        dkv = g + u[..., :, None] * ry
+        dr_ref[0, :, t] = ((st + u[..., :, None] * kv)
+                           * dy_t[..., None, :]).sum(axis=-1)
+        dk_ref[0, :, t] = (dkv * v_t[..., None, :]).sum(axis=-1)
+        dv_ref[0, :, t] = (dkv * k_t[..., :, None]).sum(axis=-2)
+        dw_ref[0, :, t] = (g * st).sum(axis=-1)
+        return w_t[..., :, None] * g + ry, du_acc + (ry * kv).sum(axis=-1)
+
+    g, du_p = jax.lax.fori_loop(0, chunk, backward,
+                                (g_scr[...], jnp.zeros_like(u)))
+    du_ref[0, 0, 0] = du_p                        # per-cell partial: summed
+    g_scr[...] = g                                # by the caller
 
     @pl.when(jr == n_spans - 1)
     def _final():
-        ds0_ref[0] = ds_in
+        ds0_ref[0] = g
 
 
 def wkv6_bwd(r, k, v, w, u, s0, dy, dsT, *, chunk: int = 64,
@@ -244,13 +266,13 @@ def wkv6_bwd(r, k, v, w, u, s0, dy, dsT, *, chunk: int = 64,
     every forward operand.  Returns (dr, dk, dv, dw, du, ds0)."""
     b, t, h, hd = r.shape
     block_h = largest_aligned_divisor(h, block_h)
-    chunk = largest_aligned_divisor(t, chunk)
+    chunk = largest_aligned_divisor(t, chunk, align=8)
     n_spans = t // chunk
-    seq = pl.BlockSpec((1, chunk, block_h, hd),
-                       lambda b_, h_, j: (b_, j, h_, 0))
+    seq = pl.BlockSpec((1, block_h, chunk, hd),
+                       lambda b_, h_, j: (b_, h_, j, 0))
     sspec = pl.BlockSpec((1, block_h, hd, hd),
                          lambda b_, h_, j: (b_, h_, 0, 0))
-    uspec = pl.BlockSpec((block_h, hd), lambda b_, h_, j: (h_, 0))
+    uspec = pl.BlockSpec((block_h, 1, hd), lambda b_, h_, j: (h_, 0, 0))
 
     spans = pl.pallas_call(
         functools.partial(_spans_kernel, span=chunk),
@@ -262,12 +284,12 @@ def wkv6_bwd(r, k, v, w, u, s0, dy, dsT, *, chunk: int = 64,
         scratch_shapes=[pltpu.VMEM((block_h, hd, hd), jnp.float32)],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
-    )(k, v, w, s0)
+    )(_heads_major(k), _heads_major(v), _heads_major(w), s0)
 
-    seq_r = pl.BlockSpec((1, chunk, block_h, hd),
-                         lambda b_, h_, j: (b_, n_spans - 1 - j, h_, 0))
+    seq_r = pl.BlockSpec((1, block_h, chunk, hd),
+                         lambda b_, h_, j: (b_, h_, n_spans - 1 - j, 0))
     out = pl.pallas_call(
-        functools.partial(_wkv_bwd_kernel, n_spans=n_spans),
+        functools.partial(_wkv_bwd_kernel, chunk=chunk, n_spans=n_spans),
         grid=(b, h // block_h, n_spans),
         in_specs=[
             seq_r, seq_r, seq_r, seq_r, uspec,
@@ -277,21 +299,25 @@ def wkv6_bwd(r, k, v, w, u, s0, dy, dsT, *, chunk: int = 64,
         ],
         out_specs=[
             seq_r, seq_r, seq_r, seq_r,
-            pl.BlockSpec((1, 1, block_h, hd),
-                         lambda b_, h_, j: (b_, n_spans - 1 - j, h_, 0)),
+            pl.BlockSpec((1, 1, 1, block_h, hd),
+                         lambda b_, h_, j: (b_, n_spans - 1 - j, h_, 0, 0)),
             sspec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, t, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_spans, h, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, t, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, n_spans, h // block_h, block_h, hd),
+                                 jnp.float32),
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_h, hd, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_h, hd, hd), jnp.float32),
+                        pltpu.VMEM((chunk, block_h, hd, hd), jnp.float32)],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
-    )(r, k, v, w, u, spans, dy, dsT)
+    )(_heads_major(r), _heads_major(k), _heads_major(v), _heads_major(w),
+      u.reshape(h, 1, hd), spans, _heads_major(dy), dsT)
     dr, dk, dv, dw, du_p, ds0 = out
-    return dr, dk, dv, dw, du_p.sum(axis=(0, 1)), ds0
+    return (_heads_major(dr), _heads_major(dk), _heads_major(dv),
+            _heads_major(dw), du_p.sum(axis=(0, 1)).reshape(h, hd), ds0)

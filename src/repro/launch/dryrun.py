@@ -31,10 +31,9 @@ from pathlib import Path  # noqa: E402
 import jax           # noqa: E402
 
 from .. import configs                     # noqa: E402
-from ..compat import cost_analysis         # noqa: E402
 from ..roofline.hlo import collective_census  # noqa: E402
 from . import policies, shapes, steps      # noqa: E402
-from .mesh import make_production_mesh, set_mesh  # noqa: E402
+from .mesh import make_production_mesh    # noqa: E402
 
 RESULTS = Path(__file__).resolve().parents[3] / "results"
 
@@ -62,14 +61,14 @@ def run_cell(arch_name: str, cell: shapes.ShapeCell, mesh_name: str,
                  "n_devices": mesh.devices.size}
     t0 = time.time()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bundle = build_bundle(arch_name, cell, mesh, scfg)
             lowered = bundle.lower()
             t_lower = time.time()
             compiled = lowered.compile()
             t_compile = time.time()
             ma = compiled.memory_analysis()
-            ca = cost_analysis(compiled)
+            ca = compiled.cost_analysis()
             txt = compiled.as_text()
             census = collective_census(txt)
             rec.update({

@@ -5,18 +5,25 @@ this module never touches jax device state.  Single-pod: 256 chips as
 (data=16, model=16).  Multi-pod: 2 pods x 256 chips as
 (pod=2, data=16, model=16) — the pod axis is the DCN-connected dimension.
 
-Mesh creation and the ambient-mesh context go through ``repro.compat`` so
-the same code runs on old and new JAX mesh APIs; ``set_mesh`` is
-re-exported here for the drivers.
+Meshes are built with Auto axis types (``make_mesh``): sharding is
+propagated by GSPMD from the ``dist.api`` constraints, not carried in
+the types.  Install one as the ambient mesh with ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
 
-from ..compat import make_mesh, set_mesh  # noqa: F401 — re-exported
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh"]
 
-__all__ = ["make_production_mesh", "make_host_mesh", "set_mesh"]
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False,

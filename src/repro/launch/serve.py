@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +59,29 @@ from ..dist.api import use_rules
 from ..dist.sharding import ShardingConfig
 from ..models import build_model
 from ..obs import get_logger
-from .mesh import make_host_mesh, set_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh
 from . import steps
 
 log = get_logger("repro.serve")
+
+
+def serving_model(cfg):
+    """The model as served: weights held in the compute dtype.
+
+    Training keeps f32 master weights; serving never needs them, and
+    initialising straight in the compute dtype (bf16) means no f32 copy
+    of the weights ever exists on a device."""
+    return build_model(replace(cfg, param_dtype=cfg.compute_dtype))
+
+
+def request_prompt(vocab_size: int, seed: int, rid: int, rows: int,
+                   prompt_len: int) -> np.ndarray:
+    """The synthetic prompt of request ``rid``: ``(rows, prompt_len)``
+    int32 tokens that depend only on ``(seed, rid)``, so the same
+    request carries the same prompt however it is batched."""
+    return np.random.default_rng((seed, rid)).integers(
+        0, vocab_size, (rows, prompt_len), dtype=np.int32)
 
 
 def serve_session(cfg, *, batch: int, prompt_len: int, gen: int,
@@ -72,11 +92,11 @@ def serve_session(cfg, *, batch: int, prompt_len: int, gen: int,
     scfg = scfg or ShardingConfig(
         data_axes=mesh.axis_names[:1], model_axes=(), fsdp_axes=(),
         kv_shard="none", remat=False)
-    model = build_model(cfg)
+    model = serving_model(cfg)
     max_len = prompt_len + gen
     rng = np.random.default_rng(seed)
 
-    with set_mesh(mesh), use_rules(scfg.rules(mesh)):
+    with jax.set_mesh(mesh), use_rules(scfg.rules(mesh)):
         params = jax.jit(model.init)(jax.random.PRNGKey(seed))
         tokens = jnp.asarray(rng.integers(
             0, cfg.vocab_size, (batch, prompt_len)), jnp.int32)
@@ -125,8 +145,16 @@ def serve_session(cfg, *, batch: int, prompt_len: int, gen: int,
 
 
 def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
-    """Per-group prefill+decode step factory shared by ``serve_stream``
-    and the split tuner (same jitted functions, same chunk contract)."""
+    """Per-group prefill+decode step factory shared by ``serve_stream``,
+    ``serve_requests`` and the split tuner (same jitted functions, same
+    chunk contract).
+
+    Each group's replica of the weights is initialised straight onto the
+    group's devices, and each chunk's tokens are placed there too, split
+    over the group's data axis where the rows divide.  ``fn(chunk)``
+    returns the greedy tokens ``(rows, gen)`` and the prefill's
+    last-position logits ``(rows, vocab)``; ``fn.params`` is the group's
+    replica."""
     max_len = prompt_len + gen
 
     def step_builder(group: DeviceGroup):
@@ -134,15 +162,20 @@ def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
         scfg = ShardingConfig(data_axes=mesh.axis_names[:1], model_axes=(),
                               fsdp_axes=(), kv_shard="none", remat=False)
         rules = scfg.rules(mesh)
-        with set_mesh(mesh), use_rules(rules):
-            params = jax.jit(model.init)(jax.random.PRNGKey(seed))
-        params = jax.device_put(params, NamedSharding(mesh, P()))
+        with jax.set_mesh(mesh), use_rules(rules):
+            params = jax.jit(model.init,
+                             out_shardings=NamedSharding(mesh, P()))(
+                jax.random.PRNGKey(seed))
         prefill = jax.jit(lambda p, t: model.prefill(p, t, max_len=max_len))
         decode = jax.jit(model.decode_step, donate_argnums=(1,))
 
         def fn(chunk):
-            with set_mesh(mesh), use_rules(rules):
-                logits, state = prefill(params, chunk["tokens"])
+            tokens = chunk["tokens"]
+            tokens = jax.device_put(tokens, NamedSharding(
+                mesh, P(rules.spec_dim("batch", tokens.shape[0]))))
+            with jax.set_mesh(mesh), use_rules(rules):
+                logits, state = prefill(params, tokens)
+                first = logits[:, -1]
                 last = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
                 outs = [last]
                 for i in range(gen - 1):
@@ -151,7 +184,9 @@ def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
                     last = jnp.argmax(logits[:, -1:],
                                       axis=-1).astype(jnp.int32)
                     outs.append(last)
-                return jnp.concatenate(outs, axis=1)
+                return {"tokens": jnp.concatenate(outs, axis=1),
+                        "logits": first}
+        fn.params = params
         return fn
 
     return step_builder
@@ -193,7 +228,7 @@ def tune_stream_split(cfg, *, groups: list[DeviceGroup], batch: int = 8,
     if len(groups) != 2:
         raise ValueError("tune_stream_split needs exactly two device groups")
     if step_builder is None:
-        model = model if model is not None else build_model(cfg)
+        model = model if model is not None else serving_model(cfg)
         step_builder = _stream_step_builder(model, prompt_len=prompt_len,
                                             gen=gen, seed=seed)
     rng = np.random.default_rng(seed)
@@ -265,7 +300,7 @@ def serve_stream(cfg, *, groups: list[DeviceGroup], n_batches: int = 4,
             f"--batch {batch} is smaller than one request per device "
             f"({n_devices}); raise --batch or use fewer devices/groups")
     if step_builder is None:
-        model = model if model is not None else build_model(cfg)
+        model = model if model is not None else serving_model(cfg)
         step_builder = _stream_step_builder(model, prompt_len=prompt_len,
                                             gen=gen, seed=seed)
     if controller is None and initial_shares is not None:
@@ -299,18 +334,28 @@ def serve_stream(cfg, *, groups: list[DeviceGroup], n_batches: int = 4,
 def serve_requests(cfg, *, groups: list[DeviceGroup], n_requests: int,
                    rate_rps: float, prompt_len: int, gen: int,
                    seed: int = 0, batcher_config=None, guard: bool = False,
-                   observer=None, row_quantum: int = 1,
+                   observer=None, row_quantum: int = 1, classes=None,
                    model=None, step_builder=None) -> dict:
     """Request-level serving on real devices: the ``repro.serve`` engine
     over a prefill+decode step builder.
 
-    Every request asks for rows of one ``(prompt_len, gen)`` shape (the
-    arrival process, priorities and SLOs come from the source's default
-    mix); the continuous batcher re-forms a scheduler batch per step
-    from whatever is queued, and the chunked scheduler splits each batch
-    across ``groups``.  Arrival waits are real ``time.sleep`` — for the
-    deterministic virtual-clock rig use ``repro.serve.make_sim_engine``
-    (the ``--sim-serve`` / ``--fault-plan`` path).
+    Every request asks for rows of one ``(prompt_len, gen)`` shape; its
+    prompt is :func:`request_prompt` of its rid.  The arrival process
+    and priorities come from the source's default mix, and the SLOs
+    from ``classes`` (``serve.RequestClass`` tuples; the source's
+    default mix when None).  The continuous batcher re-forms a
+    scheduler batch per step from whatever is queued, and the chunked
+    scheduler splits each batch across ``groups``.  Arrival waits are
+    real ``time.sleep`` — for the deterministic virtual-clock rig use
+    ``repro.serve.make_sim_engine`` (the ``--sim-serve`` /
+    ``--fault-plan`` path).
+
+    Before the first arrival, one unrebalanced scheduler step per batch
+    size the batcher can form compiles every chunk shape, so no
+    compilation is billed to a request or to the admission's service
+    estimate.  Returns the engine summary, the per-request records, the
+    scheduler (its ``history`` holds each step's group failures) and
+    the errors of steps on which every group failed.
     """
     from ..runtime import ChunkedScheduler, ServeGuard
     from ..serve import (AdmissionController, BatcherConfig,
@@ -318,26 +363,34 @@ def serve_requests(cfg, *, groups: list[DeviceGroup], n_requests: int,
                          SloPolicy)
 
     if step_builder is None:
-        model = model if model is not None else build_model(cfg)
+        model = model if model is not None else serving_model(cfg)
         step_builder = _memoize_per_group(_stream_step_builder(
             model, prompt_len=prompt_len, gen=gen, seed=seed))
-    # anchor arrivals on the engine's wall clock (the sim rig's
-    # VirtualClock starts at 0; perf_counter does not)
-    source = RequestSource(n_requests=n_requests, rate_rps=rate_rps,
-                           seed=seed, shapes=((prompt_len, gen),),
-                           rows_choices=(1, 2, 4),
-                           start=time.perf_counter())
-    rng = np.random.default_rng(seed)
+    rows_choices = (1, 2, 4)
 
-    def payload_fn(shape, rows):
-        return {"tokens": jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (rows, shape[0])), jnp.int32)}
+    def payload_fn(fb):
+        tokens = np.zeros((fb.padded_rows, prompt_len), np.int32)
+        for (lo, rows), req in zip(fb.spans, fb.requests):
+            tokens[lo:lo + rows] = request_prompt(
+                cfg.vocab_size, seed, req.rid, rows, prompt_len)
+        return {"tokens": tokens}
 
     scheduler = ChunkedScheduler(step_builder, groups,
                                  row_quantum=max(row_quantum, 1),
                                  observer=observer)
-    target = ServeGuard(scheduler) if guard else scheduler
     bcfg = batcher_config or BatcherConfig()
+    align = sum(len(g.devices) for g in groups) * scheduler.row_quantum
+    most = max(bcfg.max_batch_rows, max(rows_choices))
+    for rows in range(align, -(-most // align) * align + 1, align):
+        scheduler.step({"tokens": np.zeros((rows, prompt_len), np.int32)},
+                       rebalance=False)
+    # anchor arrivals on the engine's wall clock (the sim rig's
+    # VirtualClock starts at 0; perf_counter does not)
+    source = RequestSource(n_requests=n_requests, rate_rps=rate_rps,
+                           seed=seed, shapes=((prompt_len, gen),),
+                           rows_choices=rows_choices, classes=classes,
+                           start=time.perf_counter())
+    target = ServeGuard(scheduler) if guard else scheduler
     engine = ServeEngine(
         target, source=source,
         admission=AdmissionController(
@@ -347,7 +400,8 @@ def serve_requests(cfg, *, groups: list[DeviceGroup], n_requests: int,
     summary = engine.run()
     summary["tokens_per_s"] = summary.get("goodput_rows_per_s", 0.0) * gen
     return {"summary": summary,
-            "records": [r.record() for r in engine.done]}
+            "records": [r.record() for r in engine.done],
+            "scheduler": scheduler, "step_errors": engine.step_errors}
 
 
 def main() -> None:
@@ -448,6 +502,7 @@ def main() -> None:
                     "kill the process with SIGKILL instead of raising — "
                     "the real-process recovery drill")
     args = ap.parse_args()
+    enable_compile_cache()
     from ..obs import Observer, configure
     if args.log_level:
         configure(level=args.log_level)
@@ -622,7 +677,7 @@ def main() -> None:
             # pipeline share per-group params init + jitted
             # prefill/decode
             builder = _memoize_per_group(_stream_step_builder(
-                build_model(cfg), prompt_len=args.prompt_len, gen=args.gen,
+                serving_model(cfg), prompt_len=args.prompt_len, gen=args.gen,
                 seed=0))
         if args.tune_split:
             if len(groups) != 2:

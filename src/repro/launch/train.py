@@ -30,7 +30,8 @@ from ..obs import get_logger
 from ..optim.adamw import AdamWConfig, init_opt_state
 from ..optim.schedule import warmup_cosine
 from . import shapes, steps
-from .mesh import make_host_mesh, set_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_host_mesh
 
 log = get_logger("repro.train")
 
@@ -61,7 +62,7 @@ def train_loop(cfg, *, steps_total: int, batch: int, seq_len: int,
     batch_shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), data.batch_at(0))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = steps.make_train_step(cfg, scfg, mesh, opt_cfg, batch_shapes)
         step_fn = bundle.jit()
 
@@ -151,6 +152,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.smoke:

@@ -11,7 +11,7 @@ scheduler.  One iteration:
      advances the clock to the next arrival; a coalesce hold advances
      it to the hold horizon (new arrivals may join); a formed batch
      proceeds;
-  3. **dispatch** — build the payload (``payload_fn(shape, rows)``),
+  3. **dispatch** — build the payload (``payload_fn(formed_batch)``),
      mark requests dispatched, tick the fault injector, and run one
      scheduler (or guard) step.  The scheduler advancing the clock
      while the step runs is what makes the batching *continuous*:
@@ -76,10 +76,10 @@ from .request import Request, RequestSource
 __all__ = ["ServeEngine", "make_sim_engine"]
 
 
-def _zeros_payload(shape: tuple[int, int], rows: int) -> dict:
+def _zeros_payload(fb: FormedBatch) -> dict:
     """Default payload builder: the sim path only counts rows, so the
     feature dimension just needs to exist."""
-    return {"x": np.zeros((rows, max(shape[0], 1)), np.float32)}
+    return {"x": np.zeros((fb.padded_rows, max(fb.shape[0], 1)), np.float32)}
 
 
 class ServeEngine:
@@ -89,8 +89,7 @@ class ServeEngine:
                  source: RequestSource,
                  admission: AdmissionController | None = None,
                  batcher: ContinuousBatcher | None = None,
-                 payload_fn: Callable[[tuple[int, int], int], dict]
-                 = _zeros_payload,
+                 payload_fn: Callable[[FormedBatch], dict] = _zeros_payload,
                  injector: FaultInjector | None = None,
                  observer=None, max_steps: int | None = None,
                  wal: WalWriter | None = None,
@@ -120,6 +119,7 @@ class ServeEngine:
         self.snapshot_path = snapshot_path
         self.snapshot_every = max(int(snapshot_every), 1)
         self.replayed = 0                  # requests re-queued on restore
+        self.step_errors: list[str] = []   # steps on which every group failed
         self.done: list[Request] = []      # terminal requests, any state
         self.steps = 0
         if wal is not None and self.injector is not None:
@@ -261,7 +261,7 @@ class ServeEngine:
 
     def _dispatch(self, fb: FormedBatch) -> None:
         now = self._now()
-        payload = self.payload_fn(fb.shape, fb.padded_rows)
+        payload = self.payload_fn(fb)
         for req in fb.requests:
             req.dispatched(now)
         if self.injector is not None:
@@ -273,6 +273,7 @@ class ServeEngine:
         except RuntimeError as e:
             # every live group failed this step; single-group failures
             # never surface here (scheduler-internal re-dispatch)
+            self.step_errors.append(str(e))
             self._handle_failure(fb, str(e))
             self._after_step(cap_before)
             return

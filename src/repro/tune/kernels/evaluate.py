@@ -6,9 +6,13 @@ candidate's launch parameters.  :class:`KernelTimer` is the measurement
 oracle a :class:`~repro.tune.session.TuningSession` consumes:
 
   * **validity first** — configs that cannot launch (non-dividing
-    blocks, VMEM overflow, incompatible chunking) score ``inf`` without
-    running anything, so the search never crashes on them and they cost
-    zero experiments;
+    blocks, blocks off the TPU tile grid, VMEM overflow, incompatible
+    chunking) score ``inf`` without running anything, so the search
+    never crashes on them and they cost zero experiments.  A candidate
+    that passes validity and still fails to launch also scores ``inf``
+    — unless it is the space's default config: the kernel's own
+    defaults failing is a broken kernel, not a bad candidate, and
+    raises;
   * **parity second** — the candidate's output must match the kernel's
     ``ref.py`` oracle within the spec's tolerance, else ``inf`` (a fast
     config that computes the wrong thing must never win);
@@ -73,6 +77,8 @@ class KernelTimer:
             self.atol = max(self.atol, 2e-2)
             self.rtol = max(self.rtol, 2e-2)
         self._expected = None
+        self._default_key = self._key(spec.default_config(
+            spec.space(self.meta), self.meta))
         self._cache: dict[tuple, float] = {}
         self.n_measured = 0          # actual kernel executions (deduplicated)
         self.rejected: dict[tuple, str] = {}   # cfg key -> invalidity reason
@@ -138,6 +144,10 @@ class KernelTimer:
         try:
             return self._measure(dict(cfg))
         except Exception as exc:            # launch failure = invalid config
+            if key == self._default_key:
+                raise RuntimeError(
+                    f"{self.spec.name}: the default launch config "
+                    f"{dict(cfg)} failed at shape {self.meta}") from exc
             self.rejected[key] = f"launch failed: {type(exc).__name__}"
             return float("inf")
 

@@ -3,12 +3,16 @@
 Candidate values are shape-independent power-of-two ladders — the same
 space structure the paper tunes over (Table I lists raw combinations;
 invalid rows are never measured).  Validity is checked per shape:
-blocks must divide their extent, chunked passes must nest, and the
-per-cell VMEM footprint must fit the ~16 MiB budget (pipelined
-input/output blocks count twice for double buffering; scratch is
-allocated once).  ``dims`` is the grid-layout variant: whether the
-non-carry grid dimensions are declared ``"parallel"`` (Mosaic may
-reorder/parallelize) or ``"arbitrary"`` (strict loop nest).
+blocks must divide their extent, chunked passes must nest, every block
+dimension must sit on the TPU's (8, 128) tile grid (a multiple of 8 in
+the second-to-last position and of 128 in the last, or the whole
+extent — Mosaic refuses anything else, which interpret mode never
+checks), and the per-cell VMEM footprint must fit the ~16 MiB budget
+(pipelined input/output blocks count twice for double buffering;
+scratch is allocated once).  ``dims`` is the grid-layout variant:
+whether the non-carry grid dimensions are declared ``"parallel"``
+(Mosaic may reorder/parallelize) or ``"arbitrary"`` (strict loop
+nest).
 
 The scan kernels (``mamba_scan``, ``rwkv6_wkv``) expose a ``lanes``
 parameter selecting between the serial per-token grid program
@@ -60,6 +64,16 @@ def _divides(extent: int, block: int, name: str) -> str | None:
     return None
 
 
+def _tiled(extent: int, block: int, multiple: int, name: str) -> str | None:
+    """A block dimension on the TPU tile grid: a multiple of
+    ``multiple`` (8 for a second-to-last dim, 128 for a last dim or a
+    rank-1 block) or the whole extent."""
+    if block % multiple and block != extent:
+        return (f"{name}={block} is off the TPU tile grid (neither a "
+                f"multiple of {multiple} nor the extent {extent})")
+    return None
+
+
 def _vmem(block_bytes: int, scratch_bytes: int = 0) -> str | None:
     """Per-cell VMEM estimate.
 
@@ -87,6 +101,8 @@ def _fa_validate(cfg, meta) -> str | None:
     bq, bk, hd = cfg["block_q"], cfg["block_k"], meta["hd"]
     return (_divides(meta["tq"], bq, "block_q")
             or _divides(meta["tk"], bk, "block_k")
+            or _tiled(meta["tq"], bq, 8, "block_q")
+            or _tiled(meta["tk"], bk, 8, "block_k")
             or _vmem(_f32(2 * bq * hd + 2 * bk * hd + 3 * bq + bq * hd)))
 
 
@@ -144,6 +160,7 @@ def _da_validate(cfg, meta) -> str | None:
     if err:
         return err
     return (_divides(meta["s"] // sp, bs, "block_s")
+            or _tiled(meta["s"], bs, 8, "block_s")
             or _vmem(_f32(2 * bs * hd + 2 * rep * hd + 2 * rep),
                      _f32(rep * hd + 2 * rep)))
 
@@ -202,18 +219,21 @@ def _ms_validate(cfg, meta) -> str | None:
     bd, chunk, lanes = cfg["block_d"], cfg["chunk"], cfg["lanes"]
     t, s = meta["t"], meta["s"]
     err = (_divides(meta["di"], bd, "block_d")
-           or _divides(t, chunk, "chunk"))
+           or _divides(t, chunk, "chunk")
+           or _tiled(meta["di"], bd, 128, "block_d"))
     if err:
         return err
     if lanes == 0:           # serial grid program
-        return _vmem(_f32(3 * chunk * bd + 4 * bd * s + 2 * chunk * s + bd),
-                     _f32(bd * s))
+        return (_tiled(t, chunk, 8, "chunk")
+                or _vmem(_f32(3 * chunk * bd + 4 * bd * s + 2 * chunk * s
+                              + bd), _f32(bd * s)))
     span = chunk * lanes
     if t % span:
         return f"span chunk*lanes={span} does not divide t={t}"
     # the chunked cell stores per-token (P, Hl) scans for every lane
-    return _vmem(_f32(3 * span * bd + 4 * bd * s + 2 * span * s + bd),
-                 _f32((2 * lanes * chunk + 1) * bd * s))
+    return (_tiled(t, span, 8, "chunk*lanes")
+            or _vmem(_f32(3 * span * bd + 4 * bd * s + 2 * span * s + bd),
+                     _f32((2 * lanes * chunk + 1) * bd * s)))
 
 
 def _ms_inputs(meta, dtype, rng):
@@ -268,10 +288,12 @@ def _msb_space(meta: Mapping[str, Any]) -> ConfigSpace:
 
 def _msb_validate(cfg, meta) -> str | None:
     bd, chunk, s = cfg["block_d"], cfg["chunk"], meta["s"]
-    # the reverse cell re-traces the span forward under jax.vjp; the
-    # stacked per-token residuals (decay products + states) dominate
+    # the reverse cell recomputes the span's states into a VMEM stack
+    # and walks them back token by token
     return (_divides(meta["di"], bd, "block_d")
             or _divides(meta["t"], chunk, "chunk")
+            or _tiled(meta["di"], bd, 128, "block_d")
+            or _tiled(meta["t"], chunk, 8, "chunk")
             or _vmem(_f32(7 * chunk * bd + 6 * chunk * s + 4 * bd * s
                           + 2 * bd),
                      _f32(3 * chunk * bd * s + bd * s)))
@@ -331,8 +353,9 @@ def _wkv_validate(cfg, meta) -> str | None:
     if err:
         return err
     if lanes == 0:           # serial grid program
-        return _vmem(_f32(5 * chunk * bh * hd + bh * hd),
-                     _f32(3 * bh * hd * hd))
+        return (_tiled(t, chunk, 8, "chunk")
+                or _vmem(_f32(5 * chunk * bh * hd + bh * hd),
+                         _f32(3 * bh * hd * hd)))
     span = chunk * lanes
     if t % span:
         return f"span chunk*lanes={span} does not divide t={t}"
@@ -342,9 +365,10 @@ def _wkv_validate(cfg, meta) -> str | None:
         # tuner's parity gate also rejects any config that diverges)
         return f"chunk={chunk} exceeds matrix-form stability cap 64"
     # intra-chunk scores (chunk x chunk) per lane plus chunk temporaries
-    return _vmem(_f32(5 * span * bh * hd + bh * hd),
-                 _f32(lanes * bh * (chunk * chunk + 6 * chunk * hd)
-                      + 3 * bh * hd * hd))
+    return (_tiled(t, span, 8, "chunk*lanes")
+            or _vmem(_f32(5 * span * bh * hd + bh * hd),
+                     _f32(lanes * bh * (chunk * chunk + 6 * chunk * hd)
+                          + 3 * bh * hd * hd)))
 
 
 def _wkv_inputs(meta, dtype, rng):
@@ -400,6 +424,7 @@ def _wkvb_validate(cfg, meta) -> str | None:
     # reverse-cell residuals: per-token kv outer products + state stack
     return (_divides(meta["t"], chunk, "chunk")
             or _divides(meta["h"], bh, "block_h")
+            or _tiled(meta["t"], chunk, 8, "chunk")
             or _vmem(_f32(10 * chunk * bh * hd + 2 * bh * hd
                           + 3 * bh * hd * hd),
                      _f32(2 * chunk * bh * hd * hd)))
@@ -451,7 +476,9 @@ def _dna_space(meta: Mapping[str, Any]) -> ConfigSpace:
 
 def _dna_validate(cfg, meta) -> str | None:
     mc, cc, t = cfg["map_chunk"], cfg["count_chunk"], meta["t"]
-    err = _divides(t, mc, "map_chunk") or _divides(t, cc, "count_chunk")
+    err = (_divides(t, mc, "map_chunk") or _divides(t, cc, "count_chunk")
+           or _tiled(t, mc, 128, "map_chunk")
+           or _tiled(t, cc, 128, "count_chunk"))
     if err:
         return err
     if cc % mc:

@@ -9,7 +9,7 @@ def test_seq_sharded_decode_matches_ref():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.seq_decode import seq_decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
@@ -23,7 +23,7 @@ vn = jnp.asarray(rng.standard_normal((b, kv, hd)), jnp.float32)
 ck = jnp.asarray(rng.standard_normal((b, s, kv, hd)), jnp.float32)
 cv = jnp.asarray(rng.standard_normal((b, s, kv, hd)), jnp.float32)
 pos = jnp.int32(37)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ck_d = jax.device_put(ck, NamedSharding(mesh, P("data", "model", None, None)))
     cv_d = jax.device_put(cv, NamedSharding(mesh, P("data", "model", None, None)))
     out, ck2, cv2 = jax.jit(lambda *a: seq_decode_attention(
@@ -69,7 +69,7 @@ def test_tensor_parallel_train_step():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train_loop
 from repro.dist.sharding import ShardingConfig
 
@@ -168,7 +168,7 @@ def test_param_specs_tolerate_overlapping_axis_roles():
     out = run_subprocess("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.sharding import ShardingConfig, param_specs
 mesh = make_mesh((2, 4), ("data", "model"))
 scfg = ShardingConfig(data_axes=("data",), model_axes=("model",),
@@ -187,11 +187,11 @@ def test_compressed_allreduce_matches_mean():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.dist.compression import compressed_allreduce_mean
 mesh = make_mesh((8,), ("data",))
 x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 64)), jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
     # each shard holds one row; all-reduce-mean over rows
     got = jax.jit(lambda x: compressed_allreduce_mean(

@@ -99,7 +99,7 @@ def test_default_and_random_config_parity(name):
 @pytest.mark.parametrize("name,shape,dtype", [
     ("flash_attention", {"tq": 256, "tk": 256, "hd": 64}, jnp.bfloat16),
     ("decode_attention", {"s": 256, "hd": 64}, jnp.bfloat16),
-    ("mamba_scan", {"t": 128, "di": 96}, jnp.float32),
+    ("mamba_scan", {"t": 128, "di": 384}, jnp.float32),
     ("rwkv6_wkv", {"t": 96, "hd": 32}, jnp.float32),
     ("dna_automaton", {"t": 8192}, jnp.uint8),
 ])
@@ -109,6 +109,53 @@ def test_parity_across_shape_dtype_grid(name, shape, dtype):
     space = spec.space(meta)
     timer = KernelTimer(spec, meta, dtype, repeats=1, seed=2)
     assert np.isfinite(timer(spec.default_config(space, meta)))
+
+
+@pytest.mark.parametrize("name,cfg,meta,field", [
+    ("flash_attention", {"block_q": 12, "block_k": 128, "dims": "parallel"},
+     {"bh": 2, "tq": 120, "tk": 128, "hd": 64, "causal": True}, "block_q"),
+    ("decode_attention", {"block_s": 36, "splits": 1, "dims": "parallel"},
+     {"b": 1, "kv": 2, "rep": 4, "hd": 128, "s": 72}, "block_s"),
+    ("mamba_scan", {"block_d": 64, "chunk": 64, "lanes": 0, "unroll": 1,
+                    "dims": "parallel"},
+     {"bt": 1, "t": 128, "di": 512, "s": 16}, "block_d"),
+    ("mamba_scan_bwd", {"block_d": 256, "chunk": 4, "dims": "parallel"},
+     {"bt": 1, "t": 128, "di": 512, "s": 16}, "chunk"),
+    ("rwkv6_wkv", {"chunk": 4, "lanes": 0, "block_h": 1,
+                   "dims": "parallel"},
+     {"b": 1, "t": 64, "h": 2, "hd": 64}, "chunk"),
+    ("dna_automaton", {"map_chunk": 64, "count_chunk": 256,
+                       "dims": "parallel"}, {"t": 4096, "s": 7}, "map_chunk"),
+])
+def test_validity_rejects_blocks_off_the_tpu_tile_grid(name, cfg, meta, field):
+    """Blocks that divide their extent but break Mosaic's (8, 128)
+    rule are refused before any launch; the whole extent is legal."""
+    spec = ktune.get_kernel(name)
+    reason = spec.validate(cfg, meta)
+    assert reason is not None and "tile grid" in reason, reason
+    assert reason.startswith(field)
+
+
+def test_default_config_launch_failure_is_an_error():
+    """A non-default candidate that fails to launch scores inf; the
+    space's own default failing raises."""
+    import dataclasses
+
+    spec = ktune.get_kernel("flash_attention")
+    meta = spec.smoke_shape
+    default = spec.default_config(spec.space(meta), meta)
+
+    def run(cfg, inputs, interpret):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    broken = dataclasses.replace(spec, run=run)
+    timer = KernelTimer(broken, meta, "float32", repeats=1)
+    other = dict(default, block_q=64)
+    assert spec.validate(other, meta) is None
+    assert timer(other) == float("inf")
+    assert "launch failed" in timer.rejected[timer._key(other)]
+    with pytest.raises(RuntimeError, match="default launch config"):
+        timer(default)
 
 
 def test_invalid_config_scores_inf_without_measuring():

@@ -257,11 +257,11 @@ def test_selective_scan_chunked_matches_serial_at_every_chunk_size():
     covered = set()
     for chunk in chunks:
         for lanes in space["lanes"].values:
-            cfg = {"block_d": 32, "chunk": chunk, "lanes": lanes,
+            cfg = {"block_d": di, "chunk": chunk, "lanes": lanes,
                    "unroll": 1, "dims": "parallel"}
             if lanes == 0 or spec.validate(cfg, meta) is not None:
                 continue
-            y, h = ms_ops.selective_scan(x, delta, a, b, c, d, block_d=32,
+            y, h = ms_ops.selective_scan(x, delta, a, b, c, d, block_d=di,
                                          chunk=chunk, lanes=lanes)
             np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
                                        atol=2e-5, rtol=2e-4, err_msg=str(cfg))

@@ -40,9 +40,8 @@ def test_analytic_flops_vs_xla_cost_analysis():
     def fwd(p, bt):
         return model.loss(p, bt)[0]
 
-    from repro.compat import cost_analysis
     compiled = jax.jit(fwd).lower(params, batch).compile()
-    xla_flops = cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
 
     cell = ShapeCell("probe", "train", t, b)
     scfg = ShardingConfig(remat=False, fsdp_axes=(), microbatches=1)
@@ -57,22 +56,22 @@ def test_census_trip_weighting():
     out = run_subprocess("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh, set_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo import collective_census
 mesh = make_mesh((8,), ("d",))
 
 def step(x, _):
     # explicit psum inside the scan body -> a real all-reduce per trip
-    local = shard_map(lambda xl: xl + 1e-3 * jax.lax.psum(xl, "d"),
-                      mesh, in_specs=P("d", None),
-                      out_specs=P("d", None))(x)
+    local = jax.shard_map(lambda xl: xl + 1e-3 * jax.lax.psum(xl, "d"),
+                          mesh=mesh, in_specs=P("d", None),
+                          out_specs=P("d", None), check_vma=False)(x)
     return local, None
 
 def fn(x):
     y, _ = jax.lax.scan(step, x, None, length=12)
     return y.sum()
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(fn, in_shardings=NamedSharding(mesh, P("d", None)),
                 out_shardings=NamedSharding(mesh, P())) \
         .lower(jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
