@@ -170,20 +170,24 @@ def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
         decode = jax.jit(model.decode_step, donate_argnums=(1,))
 
         def fn(chunk):
-            tokens = chunk["tokens"]
-            tokens = jax.device_put(tokens, NamedSharding(
-                mesh, P(rules.spec_dim("batch", tokens.shape[0]))))
-            with jax.set_mesh(mesh), use_rules(rules):
-                logits, state = prefill(params, tokens)
-                first = logits[:, -1]
-                last = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-                outs = [last]
-                for i in range(gen - 1):
-                    logits, state = decode(params, state, last,
-                                           jnp.int32(prompt_len + i))
+            with jax.profiler.TraceAnnotation("step.prefill"):
+                tokens = chunk["tokens"]
+                tokens = jax.device_put(tokens, NamedSharding(
+                    mesh, P(rules.spec_dim("batch", tokens.shape[0]))))
+                with jax.set_mesh(mesh), use_rules(rules):
+                    logits, state = prefill(params, tokens)
+                    first = logits[:, -1]
                     last = jnp.argmax(logits[:, -1:],
                                       axis=-1).astype(jnp.int32)
-                    outs.append(last)
+            with jax.set_mesh(mesh), use_rules(rules):
+                outs = [last]
+                with jax.profiler.TraceAnnotation("step.decode"):
+                    for i in range(gen - 1):
+                        logits, state = decode(params, state, last,
+                                               jnp.int32(prompt_len + i))
+                        last = jnp.argmax(logits[:, -1:],
+                                          axis=-1).astype(jnp.int32)
+                        outs.append(last)
                 return {"tokens": jnp.concatenate(outs, axis=1),
                         "logits": first}
         fn.params = params

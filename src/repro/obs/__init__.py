@@ -31,9 +31,10 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       default_latency_buckets)
 from .provenance import build_meta, git_sha
 from .report import render, summarize, write_summary
-from .trace import Tracer, load_trace, validate_trace
+from .trace import SPANS, Tracer, load_trace, validate_trace
 
 __all__ = [
+    "SPANS",
     "EVENT_KINDS", "Journal", "load_journal", "validate_events",
     "LEVELS", "StructuredLogger", "configure", "get_logger",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",

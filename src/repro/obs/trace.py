@@ -26,6 +26,14 @@ event append order across drain threads; sort by ``ts`` to compare).
 Lanes: ``tid`` is a small stable integer chosen by the caller (the
 scheduler uses the group index, never an OS thread id), so traces are
 comparable across runs and machines.
+
+Real runs record on the profiler's clock instead: the serving path opens
+a ``jax.profiler.TraceAnnotation`` named in :data:`SPANS` at each of its
+layer boundaries, so one ``jax.profiler`` trace carries the host's spans
+beside the device's events (``docs/observability.md``, "Profiling a real
+run").  An annotation records only while a profiler session is open and
+costs an object and an enter/exit otherwise; the ``Tracer`` above stays
+the sim drills' virtual-clock record.
 """
 
 from __future__ import annotations
@@ -37,9 +45,27 @@ from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Mapping
 
-__all__ = ["Tracer", "load_trace", "validate_trace"]
+__all__ = ["SPANS", "Tracer", "load_trace", "validate_trace"]
 
 _US = 1e6     # Chrome trace timestamps are microseconds
+
+# The serving path's profiler spans: name -> (layer, what reads it).
+# ``idle_gaps`` is the benchmark's breakdown, which names each long idle
+# gap of the device by the innermost host span over it.
+SPANS: dict[str, tuple[str, str]] = {
+    "serve.wait": ("serving loop", "host_gap_ms_per_step"),
+    "serve.ingest": ("admission and batcher", "idle_gaps"),
+    "serve.form": ("admission and batcher", "idle_gaps"),
+    "serve.step": ("serving loop", "host_gap_ms_per_step"),
+    "serve.payload": ("serving loop", "idle_gaps"),
+    "serve.retire": ("serving loop", "idle_gaps"),
+    "sched.plan": ("scheduler step", "idle_gaps"),
+    "sched.dispatch": ("scheduler step", "idle_gaps"),
+    "sched.drain": ("scheduler step", "idle_gaps"),
+    "sched.wait": ("scheduler step", "idle_gaps"),
+    "step.prefill": ("model step", "idle_gaps"),
+    "step.decode": ("model step", "decode_dispatch_us_per_step"),
+}
 
 
 class Tracer:

@@ -548,32 +548,34 @@ class ChunkedScheduler:
         never feed the controller (their times are recovery-tainted).
         Raises ``RuntimeError`` if every group fails.
         """
-        n = jax.tree.leaves(batch)[0].shape[0]
-        rows, plan_changed = self._planned_rows(n, rebalance)
-        plan = self._plans[(n, self._live_key())]
+        with jax.profiler.TraceAnnotation("sched.plan"):
+            n = jax.tree.leaves(batch)[0].shape[0]
+            rows, plan_changed = self._planned_rows(n, rebalance)
+            plan = self._plans[(n, self._live_key())]
 
-        # contiguous per-group row ranges, then per-group chunk slices
-        # (sizes come from the plan cache — no recompute per step);
-        # each chunk carries its batch-row span so per-row completion
-        # instants can be attributed back to the rows (and, one layer
-        # up, to the requests) it served
-        offsets = np.concatenate([[0], np.cumsum(rows)])
-        chunks: list[list[dict]] = []
-        chunk_rows: list[list[int]] = []
-        chunk_spans: list[list[list[tuple[int, int]]]] = []
-        for gi, g in enumerate(self.groups):
-            sizes = plan["chunks"][gi]
-            lo = int(offsets[gi])
-            group_chunks = []
-            group_spans = []
-            for s in sizes:
-                sl = jax.tree.map(lambda x, lo=lo, s=s: x[lo:lo + s], batch)
-                group_chunks.append(constrain_leading(sl))
-                group_spans.append([(lo, s)])
-                lo += s
-            chunks.append(group_chunks)
-            chunk_rows.append(list(sizes))
-            chunk_spans.append(group_spans)
+            # contiguous per-group row ranges, then per-group chunk
+            # slices (sizes come from the plan cache — no recompute per
+            # step); each chunk carries its batch-row span so per-row
+            # completion instants can be attributed back to the rows
+            # (and, one layer up, to the requests) it served
+            offsets = np.concatenate([[0], np.cumsum(rows)])
+            chunks: list[list[dict]] = []
+            chunk_rows: list[list[int]] = []
+            chunk_spans: list[list[list[tuple[int, int]]]] = []
+            for gi, g in enumerate(self.groups):
+                sizes = plan["chunks"][gi]
+                lo = int(offsets[gi])
+                group_chunks = []
+                group_spans = []
+                for s in sizes:
+                    sl = jax.tree.map(lambda x, lo=lo, s=s: x[lo:lo + s],
+                                      batch)
+                    group_chunks.append(constrain_leading(sl))
+                    group_spans.append([(lo, s)])
+                    lo += s
+                chunks.append(group_chunks)
+                chunk_rows.append(list(sizes))
+                chunk_spans.append(group_spans)
 
         t0 = self._now()
         n_groups = len(self.groups)
@@ -629,7 +631,8 @@ class ChunkedScheduler:
         def drain_one(gi: int) -> bool:
             res, r, planned, spans = pending[gi].popleft()
             try:
-                self._block(res)
+                with jax.profiler.TraceAnnotation("sched.drain", group=gi):
+                    self._block(res)
             except Exception as e:  # noqa: BLE001 — demotion boundary
                 fail(gi, e)
                 return False
@@ -645,7 +648,9 @@ class ChunkedScheduler:
                 self._obs.tracer.instant("dispatch", tid=gi,
                                          args={"rows": r})
             try:
-                res = self._fns[gi](chunk)
+                with jax.profiler.TraceAnnotation("sched.dispatch", group=gi,
+                                                  rows=r):
+                    res = self._fns[gi](chunk)
             except Exception as e:  # noqa: BLE001 — demotion boundary
                 fail(gi, e)
                 return False
@@ -678,18 +683,20 @@ class ChunkedScheduler:
         futures = {gi: self._drain_pool.submit(drain_group, gi)
                    for gi in range(n_groups)
                    if pending[gi] and gi not in failures}
-        for gi, f in futures.items():
-            try:
-                f.result(timeout=self.dispatch_timeout_s)
-            except FutureTimeoutError:
-                fail(gi, f"drain timed out after {self.dispatch_timeout_s}s")
-                # the worker is still blocked on the dead dispatch — the
-                # pool cannot be reused safely, so a fresh one is built
-                # lazily on the next step
-                pool = getattr(self, "_pool", None)
-                if pool is not None:
-                    pool.shutdown(wait=False)
-                    self._pool = None
+        with jax.profiler.TraceAnnotation("sched.wait"):
+            for gi, f in futures.items():
+                try:
+                    f.result(timeout=self.dispatch_timeout_s)
+                except FutureTimeoutError:
+                    fail(gi, "drain timed out after "
+                             f"{self.dispatch_timeout_s}s")
+                    # the worker is still blocked on the dead dispatch —
+                    # the pool cannot be reused safely, so a fresh one is
+                    # built lazily on the next step
+                    pool = getattr(self, "_pool", None)
+                    if pool is not None:
+                        pool.shutdown(wait=False)
+                        self._pool = None
 
         # -- demote failed groups and re-dispatch their orphans ------------
         redispatched = 0

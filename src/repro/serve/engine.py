@@ -61,6 +61,7 @@ from __future__ import annotations
 import time
 from typing import Callable
 
+import jax
 import numpy as np
 
 from ..obs import as_observer
@@ -125,11 +126,6 @@ class ServeEngine:
         if wal is not None and self.injector is not None:
             self.injector.attach_wal(wal)
         self._obs = as_observer(observer) or self.scheduler._obs
-        if self._obs is not None:
-            m = self._obs.metrics
-            self._h_queue = m.histogram("serve.queue_delay_s")
-            self._h_service = m.histogram("serve.service_s")
-            self._h_e2e = m.histogram("serve.e2e_s")
 
     # -- clock / capacity ---------------------------------------------------
     def _now(self) -> float:
@@ -137,10 +133,11 @@ class ServeEngine:
 
     def _wait_until(self, t: float) -> None:
         clock = self.scheduler.clock
-        if clock is not None and hasattr(clock, "advance_to"):
-            clock.advance_to(t)
-        else:
-            time.sleep(max(t - self._now(), 0.0))
+        with jax.profiler.TraceAnnotation("serve.wait"):
+            if clock is not None and hasattr(clock, "advance_to"):
+                clock.advance_to(t)
+            else:
+                time.sleep(max(t - self._now(), 0.0))
 
     def _degraded(self) -> bool:
         if self.guard is not None:
@@ -172,22 +169,23 @@ class ServeEngine:
 
     # -- lifecycle steps ----------------------------------------------------
     def _ingest(self, now: float) -> None:
-        degraded = self._degraded()
-        for req in self.source.take_until(now):
-            reason = self.admission.admit(req, now, self.batcher.queued_rows,
-                                          degraded=degraded)
-            if reason is None:
-                req.admit(now)
-                self.batcher.push(req)
-                if self.wal is not None:
-                    self.wal.append("admit", **req.wal_fields(),
-                                    replayed=req.replayed)
-                self._count("serve.admitted")
-                self._j("request_admitted", rid=req.rid, rows=req.rows,
-                        shape=list(req.shape), klass=req.klass,
-                        queued_rows=self.batcher.queued_rows)
-            else:
-                self._shed(req, now, reason)
+        with jax.profiler.TraceAnnotation("serve.ingest"):
+            degraded = self._degraded()
+            for req in self.source.take_until(now):
+                reason = self.admission.admit(
+                    req, now, self.batcher.queued_rows, degraded=degraded)
+                if reason is None:
+                    req.admit(now)
+                    self.batcher.push(req)
+                    if self.wal is not None:
+                        self.wal.append("admit", **req.wal_fields(),
+                                        replayed=req.replayed)
+                    self._count("serve.admitted")
+                    self._j("request_admitted", rid=req.rid, rows=req.rows,
+                            shape=list(req.shape), klass=req.klass,
+                            queued_rows=self.batcher.queued_rows)
+                else:
+                    self._shed(req, now, reason)
 
     def _shed(self, req: Request, now: float, reason: str) -> None:
         req.shed(now, reason)
@@ -216,10 +214,6 @@ class ServeEngine:
                 self.wal.append("retire", rid=req.rid, status="completed",
                                 t_done=req.t_done, retries=req.retries)
             self._count("serve.completed")
-            if self._obs is not None:
-                self._h_queue.observe(req.queue_delay_s)
-                self._h_service.observe(req.service_s)
-                self._h_e2e.observe(req.latency_s)
             self._j("request_retired", rid=req.rid, klass=req.klass,
                     retries=req.retries, replayed=req.replayed,
                     queue_delay_s=round(req.queue_delay_s, 9),
@@ -260,26 +254,34 @@ class ServeEngine:
                 self._shed(req, now, reason)
 
     def _dispatch(self, fb: FormedBatch) -> None:
-        now = self._now()
-        payload = self.payload_fn(fb)
-        for req in fb.requests:
-            req.dispatched(now)
-        if self.injector is not None:
-            self.injector.tick()
-        cap_before = self._capacity()
-        try:
-            rec = self.guard.step(payload) if self.guard is not None \
-                else self.scheduler.step(payload)
-        except RuntimeError as e:
-            # every live group failed this step; single-group failures
-            # never surface here (scheduler-internal re-dispatch)
-            self.step_errors.append(str(e))
-            self._handle_failure(fb, str(e))
+        # the step index the WAL's ``step`` record and each request's
+        # record carry: the profiler span ties back to the requests
+        step = self.steps + 1
+        with jax.profiler.TraceAnnotation(
+                "serve.step", step=step, rows=fb.rows,
+                padded_rows=fb.padded_rows):
+            now = self._now()
+            with jax.profiler.TraceAnnotation("serve.payload"):
+                payload = self.payload_fn(fb)
+            for req in fb.requests:
+                req.dispatched(now, step)
+            if self.injector is not None:
+                self.injector.tick()
+            cap_before = self._capacity()
+            try:
+                rec = self.guard.step(payload) if self.guard is not None \
+                    else self.scheduler.step(payload)
+            except RuntimeError as e:
+                # every live group failed this step; single-group failures
+                # never surface here (scheduler-internal re-dispatch)
+                self.step_errors.append(str(e))
+                self._handle_failure(fb, str(e))
+                self._after_step(cap_before)
+                return
+            self.admission.estimator.observe(rec["t_step"], fb.padded_rows)
+            with jax.profiler.TraceAnnotation("serve.retire"):
+                self._retire(fb, rec)
             self._after_step(cap_before)
-            return
-        self.admission.estimator.observe(rec["t_step"], fb.padded_rows)
-        self._retire(fb, rec)
-        self._after_step(cap_before)
 
     # -- durability ---------------------------------------------------------
     def save_state_snapshot(self) -> None:
@@ -394,9 +396,11 @@ class ServeEngine:
         while True:
             now = self._now()
             self._ingest(now)
-            fb = self.batcher.form(now, next_arrival=self.source.next_time(),
-                                   align=self._align(),
-                                   flush=self.source.exhausted)
+            with jax.profiler.TraceAnnotation("serve.form"):
+                fb = self.batcher.form(now,
+                                       next_arrival=self.source.next_time(),
+                                       align=self._align(),
+                                       flush=self.source.exhausted)
             if fb is None:
                 nxt = self.source.next_time()
                 if nxt is None:

@@ -86,6 +86,7 @@ class Request:
     t_admit: float | None = None
     t_dispatch: float | None = None
     t_done: float | None = None
+    step: int | None = None          # engine step of the last dispatch
     shed_reason: str | None = field(default=None)
     # True when this request was rebuilt from the write-ahead log after
     # a crash and re-entered admission (at-least-once replay); completion
@@ -113,6 +114,14 @@ class Request:
     @property
     def terminal(self) -> bool:
         return self.status in ("completed", "shed")
+
+    @property
+    def admit_lag_s(self) -> float | None:
+        """Arrival -> first admission: how long the request waited for
+        the engine to take it from the source (None until admitted)."""
+        if self.t_admit is None:
+            return None
+        return self.t_admit - self.t_arrival
 
     @property
     def queue_delay_s(self) -> float | None:
@@ -165,9 +174,10 @@ class Request:
         self._to("batched")
         return self
 
-    def dispatched(self, now: float) -> "Request":
+    def dispatched(self, now: float, step: int | None = None) -> "Request":
         self._to("dispatched")
         self.t_dispatch = float(now)
+        self.step = step
         return self
 
     def completed(self, done_at: float) -> "Request":
@@ -194,7 +204,9 @@ class Request:
             "klass": self.klass, "priority": self.priority,
             "status": self.status, "retries": self.retries,
             "shed_reason": self.shed_reason,
-            "t_arrival": self.t_arrival, "t_done": self.t_done,
+            "t_arrival": self.t_arrival, "t_admit": self.t_admit,
+            "t_done": self.t_done, "step": self.step,
+            "admit_lag_s": self.admit_lag_s,
             "queue_delay_s": self.queue_delay_s,
             "service_s": self.service_s,
             "latency_s": self.latency_s,
