@@ -49,22 +49,22 @@ __all__ = ["SPANS", "Tracer", "load_trace", "validate_trace"]
 
 _US = 1e6     # Chrome trace timestamps are microseconds
 
-# The serving path's profiler spans: name -> (layer, what reads it).
-# ``idle_gaps`` is the benchmark's breakdown, which names each long idle
-# gap of the device by the innermost host span over it.
-SPANS: dict[str, tuple[str, str]] = {
-    "serve.wait": ("serving loop", "host_gap_ms_per_step"),
-    "serve.ingest": ("admission and batcher", "idle_gaps"),
-    "serve.form": ("admission and batcher", "idle_gaps"),
-    "serve.step": ("serving loop", "host_gap_ms_per_step"),
-    "serve.payload": ("serving loop", "idle_gaps"),
-    "serve.retire": ("serving loop", "idle_gaps"),
-    "sched.plan": ("scheduler step", "idle_gaps"),
-    "sched.dispatch": ("scheduler step", "idle_gaps"),
-    "sched.drain": ("scheduler step", "idle_gaps"),
-    "sched.wait": ("scheduler step", "idle_gaps"),
-    "step.prefill": ("model step", "idle_gaps"),
-    "step.decode": ("model step", "decode_dispatch_us_per_step"),
+# The serving path's profiler spans, name -> layer.  Call sites open
+# ``jax.profiler.TraceAnnotation(name)`` with these names; the tests hold
+# every serving-path span in a profiler trace to this table.
+SPANS: dict[str, str] = {
+    "serve.wait": "serving loop",
+    "serve.ingest": "admission and batcher",
+    "serve.form": "admission and batcher",
+    "serve.step": "serving loop",
+    "serve.payload": "serving loop",
+    "serve.retire": "serving loop",
+    "sched.plan": "scheduler step",
+    "sched.dispatch": "scheduler step",
+    "sched.drain": "scheduler step",
+    "sched.wait": "scheduler step",
+    "step.prefill": "model step",
+    "step.decode": "model step",
 }
 
 

@@ -45,12 +45,26 @@ WAIT = [["serve.wait", 4_500_000, MS]]
 
 
 def test_host_gap_leaves_out_idle_time_under_serve_wait():
-    # idle from the first step on: 4-4.5, 5.5-6 and 9-10 ms over 2 steps
+    # idle from the first step's start to the last's end (1-9.5 ms):
+    # 4-4.5, 5.5-6 and 9-9.5 ms over 2 steps
     assert read("host_gap_ms_per_step",
-                traced(STEPS + WAIT, OPS)) == pytest.approx(1.0)
+                traced(STEPS + WAIT, OPS)) == pytest.approx(0.75)
     # without the wait its millisecond counts as host gap
     assert read("host_gap_ms_per_step",
-                traced(STEPS, OPS)) == pytest.approx(1.5)
+                traced(STEPS, OPS)) == pytest.approx(1.25)
+
+
+def test_host_gap_ends_with_the_last_step():
+    # the device idles from 9 ms to the stretch's end at 10 ms; only the
+    # half millisecond before the last step ends is a step's gap, however
+    # long the engine's exit and the harness's close take after it
+    run = traced(STEPS + WAIT, OPS)
+    run.trace["t1_ns"] = 60 * MS
+    assert read("host_gap_ms_per_step", run) == pytest.approx(0.75)
+    # a step that ends later owns the idle time up to its end, 10.5 ms
+    late = [["serve.step", 1 * MS, 3_500_000], ["serve.step", 5_500_000, 5 * MS]]
+    assert read("host_gap_ms_per_step",
+                traced(late + WAIT, OPS)) == pytest.approx(1.25)
 
 
 def test_host_gap_averages_the_devices():
@@ -58,7 +72,7 @@ def test_host_gap_averages_the_devices():
     run.trace["devices"].append({"name": "/device:TPU:1", "modules": [],
                                  "ops": [["d", 1 * MS, 9 * MS]]})
     # the second device idles nowhere after the first step starts
-    assert read("host_gap_ms_per_step", run) == pytest.approx(0.5)
+    assert read("host_gap_ms_per_step", run) == pytest.approx(0.375)
 
 
 def test_decode_dispatch_divides_by_gen_minus_one_per_span():
@@ -69,6 +83,29 @@ def test_decode_dispatch_divides_by_gen_minus_one_per_span():
                 traced(host, OPS, gen=5)) == pytest.approx(100.0)
     assert read("decode_dispatch_us_per_step",
                 traced(host, OPS, gen=9)) == pytest.approx(50.0)
+
+
+PREPARE = "CommonPjRtLoadedExecutable::ExecutePrepare"
+ALLOCATE = "AllocateOutputBuffersWithInputReuse"
+
+
+def test_decode_dispatch_leaves_out_the_runtime_waits():
+    # a 2 ms decode loop of gen - 1 = 4 steps: one dispatch held 1 ms in
+    # ExecutePrepare, of which 0.1 ms allocated its outputs (host work),
+    # and a 0.3 ms AllocateBufferAwait; a wait outside the span is not
+    # the loop's
+    host = [["step.decode", 1 * MS, 2 * MS],
+            [PREPARE, 1_200_000, 1 * MS],
+            [ALLOCATE, 2_100_000, 100_000],
+            ["AllocateBufferAwait", 2_500_000, 300_000],
+            [PREPARE, 4 * MS, 1 * MS]]
+    # 2 ms - 0.9 ms - 0.3 ms of the host's own, over 4 steps
+    assert read("decode_dispatch_us_per_step",
+                traced(host, OPS, gen=5)) == pytest.approx(200.0)
+    # a wait nested in another is counted once
+    nested = host + [["AllocateBufferAwait", 1_300_000, 200_000]]
+    assert read("decode_dispatch_us_per_step",
+                traced(nested, OPS, gen=5)) == pytest.approx(200.0)
 
 
 @pytest.mark.parametrize("name", ["host_gap_ms_per_step",
