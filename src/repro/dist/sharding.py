@@ -212,9 +212,9 @@ def cache_specs(shapes: Any, mesh: Mesh, scfg: ShardingConfig) -> Any:
     """PartitionSpec tree for stacked decode state.
 
     Leaves carry a leading per-group stack axis.  Attention KV caches —
-    the 5-D ``(G, B, S, KV, hd)`` leaves keyed ``"k"``/``"v"`` — are laid
+    the 5-D ``(G, S, KV, B, hd)`` leaves keyed ``"k"``/``"v"`` — are laid
     out per ``kv_shard``; every other state leaf (SSM / RWKV / conv,
-    including the 5-D ``"wkv"`` state) shards batch only.
+    including the 5-D ``"wkv"`` state) shards batch (dim 1) only.
     """
     batch = scfg.batch_axes(mesh)
     kv_seq = scfg.kv_seq_axes(mesh)
@@ -225,9 +225,9 @@ def cache_specs(shapes: Any, mesh: Mesh, scfg: ShardingConfig) -> Any:
         shape = tuple(l.shape)
         key = getattr(path[-1], "key", None) if path else None
         if len(shape) == 5 and key in ("k", "v"):
-            return P(None, _dim_entry(mesh, batch, shape[1]),
-                     _dim_entry(mesh, kv_seq, shape[2]),
-                     _dim_entry(mesh, kv_heads, shape[3]), None)
+            return P(None, _dim_entry(mesh, kv_seq, shape[1]),
+                     _dim_entry(mesh, kv_heads, shape[2]),
+                     _dim_entry(mesh, batch, shape[3]), None)
         if len(shape) >= 2:
             return P(None, _dim_entry(mesh, batch, shape[1]),
                      *([None] * (len(shape) - 2)))

@@ -58,7 +58,7 @@ from ..core.hetero import DeviceGroup
 from ..dist.api import use_rules
 from ..dist.sharding import ShardingConfig
 from ..models import build_model
-from ..obs import get_logger
+from ..obs import as_observer, get_logger
 from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 from . import steps
@@ -144,17 +144,43 @@ def serve_session(cfg, *, batch: int, prompt_len: int, gen: int,
     }
 
 
-def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
+def compile_decode(decode, params, state, tokens, pos, observer=None):
+    """Compile the jitted decode step for these arguments (arrays, or
+    ``ShapeDtypeStruct``s with shardings) ahead of its first call, and
+    return the compiled program.
+
+    Called with the arrays of a chunk's first decode call, the jitted
+    ``decode`` then runs this program without compiling again.  The
+    program's temporary bytes, which a decode that copies its cache
+    needs a cache's worth of, go to ``observer``'s metrics (gauge
+    ``decode.temp_bytes``, the largest over the shapes compiled) and to
+    the log, once per chunk shape; nothing is recorded per call.
+    """
+    compiled = decode.lower(params, state, tokens, pos).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    obs = as_observer(observer)
+    if obs is not None:
+        gauge = obs.metrics.gauge("decode.temp_bytes")
+        gauge.set(max(gauge.value or 0, temp))
+    log.info(f"decode program for {tokens.shape[0]} rows: {temp} "
+             "temporary bytes")
+    return compiled
+
+
+def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int,
+                         observer=None):
     """Per-group prefill+decode step factory shared by ``serve_stream``,
     ``serve_requests`` and the split tuner (same jitted functions, same
     chunk contract).
 
     Each group's replica of the weights is initialised straight onto the
     group's devices, and each chunk's tokens are placed there too, split
-    over the group's data axis where the rows divide.  ``fn(chunk)``
-    returns the greedy tokens ``(rows, gen)`` and the prefill's
-    last-position logits ``(rows, vocab)``; ``fn.params`` is the group's
-    replica."""
+    over the group's data axis where the rows divide.  The first chunk of
+    each shape compiles its decode ahead of the first call
+    (:func:`compile_decode`, recording into ``observer``).
+    ``fn(chunk)`` returns the greedy tokens ``(rows, gen)`` and the
+    prefill's last-position logits ``(rows, vocab)``; ``fn.params`` is
+    the group's replica."""
     max_len = prompt_len + gen
 
     def step_builder(group: DeviceGroup):
@@ -168,6 +194,7 @@ def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
                 jax.random.PRNGKey(seed))
         prefill = jax.jit(lambda p, t: model.prefill(p, t, max_len=max_len))
         decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        compiled: set[tuple] = set()        # chunk shapes decode compiled for
 
         def fn(chunk):
             with jax.profiler.TraceAnnotation("step.prefill"):
@@ -180,6 +207,10 @@ def _stream_step_builder(model, *, prompt_len: int, gen: int, seed: int):
                     last = jnp.argmax(logits[:, -1:],
                                       axis=-1).astype(jnp.int32)
             with jax.set_mesh(mesh), use_rules(rules):
+                if tokens.shape not in compiled:
+                    compile_decode(decode, params, state, last,
+                                   jnp.int32(prompt_len), observer)
+                    compiled.add(tokens.shape)
                 outs = [last]
                 with jax.profiler.TraceAnnotation("step.decode"):
                     for i in range(gen - 1):
@@ -306,7 +337,8 @@ def serve_stream(cfg, *, groups: list[DeviceGroup], n_batches: int = 4,
     if step_builder is None:
         model = model if model is not None else serving_model(cfg)
         step_builder = _stream_step_builder(model, prompt_len=prompt_len,
-                                            gen=gen, seed=seed)
+                                            gen=gen, seed=seed,
+                                            observer=observer)
     if controller is None and initial_shares is not None:
         controller = EwmaController(len(groups),
                                     shares=np.asarray(initial_shares))
@@ -369,7 +401,8 @@ def serve_requests(cfg, *, groups: list[DeviceGroup], n_requests: int,
     if step_builder is None:
         model = model if model is not None else serving_model(cfg)
         step_builder = _memoize_per_group(_stream_step_builder(
-            model, prompt_len=prompt_len, gen=gen, seed=seed))
+            model, prompt_len=prompt_len, gen=gen, seed=seed,
+            observer=observer))
     rows_choices = (1, 2, 4)
 
     def payload_fn(fb):

@@ -20,6 +20,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..dist.api import constrain
 from .config import ArchConfig
@@ -154,7 +155,8 @@ def full_attention(p: Params, x: jax.Array, cfg: ArchConfig, *,
 
     ``kv_states`` switches to cross-attention (keys/values from the encoder
     stream, no RoPE on either side for enc-dec models).  ``return_kv``
-    additionally returns the (pre-repeat) keys/values for cache fills.
+    additionally returns the (pre-repeat) keys/values in the cache's
+    layout (:func:`to_cache`), for cache fills.
     """
     q = _project_q(p, x, cfg, positions if kv_states is None else None)
     src = x if kv_states is None else kv_states
@@ -178,86 +180,144 @@ def full_attention(p: Params, x: jax.Array, cfg: ArchConfig, *,
     res = jnp.einsum("btnh,nhd->btd", out.astype(dt), p["wo"].astype(dt))
     res = constrain(res, "batch", "seq", None)
     if return_kv:
-        return res, {"k": k, "v": v}
+        return res, {"k": to_cache(k), "v": to_cache(v)}
     return res
 
 
 # -- decode -------------------------------------------------------------------
+#
+# The KV cache is stored as (S, KV, B, hd) per layer, hd padded to whole
+# 128-lane rows; LM.decode_step carries every layer's, stacked on a leading
+# axis.  That is the layout a TPU's decode attention reads (head dim in
+# lanes, batch rows in sublanes), and with hd padded it is also the default
+# layout XLA gives the stored array, so no program copies the cache to
+# attend and none needs a layout of its own at its entry or result.  A
+# (B, S, KV, 96) cache gets slots in lanes by default, which attention
+# cannot read without a copy of the cache.
+
+LANES = 128
+
+
+def cache_head_dim(hd: int) -> int:
+    """Head size as the cache stores it: padded to whole lane rows."""
+    return -(-hd // LANES) * LANES
+
+
+def to_cache(x: jax.Array) -> jax.Array:
+    """Keys or values (B, T, KV, hd) -> the cache's (T, KV, B, hd padded)."""
+    pad = cache_head_dim(x.shape[-1]) - x.shape[-1]
+    x = jnp.transpose(x, (1, 2, 0, 3))
+    return jnp.pad(x, ((0, 0),) * 3 + ((0, pad),)) if pad else x
+
+
+def from_cache(c: jax.Array, hd: int) -> jax.Array:
+    """The cache's (S, KV, B, hd padded) -> keys or values (B, S, KV, hd)."""
+    return jnp.transpose(c, (2, 0, 1, 3))[..., :hd]
+
 
 def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int,
                   dtype=None) -> Params:
     dt = dtype or jnp.dtype(cfg.compute_dtype)
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    shape = (max_len, cfg.n_kv_heads, batch, cache_head_dim(cfg.head_dim))
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
 def decode_attention(p: Params, x: jax.Array, cache: Params,
                      cfg: ArchConfig, *, pos: jax.Array,
+                     layer: jax.Array | None = None,
                      cross: bool = False) -> tuple[jax.Array, Params]:
-    """One-token decode. x: (B, 1, D); cache k/v: (B, S, KV, hd).
+    """One-token decode. x: (B, 1, D); cache k/v: (S, KV, B, hd padded).
 
     ``pos`` is the current position (scalar int32): the new KV is written
     at ``pos`` and attention spans positions <= pos.  For cross-attention
     the cache holds precomputed encoder KV and is not updated.
+
+    With ``layer`` the cache is every layer's, stacked on a leading axis:
+    the new row is written in place at ``(layer, pos)`` and the layer is
+    read from the stack, which XLA fuses into the attention dots, so no
+    layer is copied.
     """
     from ..dist.api import current_rules
 
     b = x.shape[0]
+    hd = cfg.head_dim
     q = _project_q(p, x, cfg, None if cross else jnp.full((b, 1), pos))
     rules = current_rules()
     kvseq_axes = tuple(rules.rules.get("kv_seq", ())) if rules else ()
     batch_axes = tuple(rules.rules.get("batch", ())) if rules else ()
+    seq_dim = 0 if layer is None else 1
     if kvseq_axes:
         # the sharded path needs shard_map-divisible extents; fall back to
         # the dense path otherwise (rules are hints, not hard partitioning)
-        if cache["k"].shape[1] % rules.axes_size(kvseq_axes) \
+        if cache["k"].shape[seq_dim] % rules.axes_size(kvseq_axes) \
                 or (batch_axes and b % rules.axes_size(batch_axes)):
             kvseq_axes = ()
+
+    def read(c):
+        return c if layer is None else \
+            jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+
     if not cross and kvseq_axes:
         # sequence-sharded cache: shard_map'd local update + flash-decode
-        # with cross-shard logsumexp combine (see dist.seq_decode).
+        # with cross-shard logsumexp combine (see dist.seq_decode), on the
+        # layer in (B, S, KV, hd) form.
         from ..dist.seq_decode import seq_decode_attention
         k_new, v_new = _project_kv(p, x, cfg, jnp.full((b, 1), pos))
         out32, ck, cv = seq_decode_attention(
-            q[:, 0], k_new[:, 0], v_new[:, 0], cache["k"], cache["v"], pos,
-            mesh=rules.mesh, seq_axes=kvseq_axes, batch_axes=batch_axes)
-        cache = {"k": ck, "v": cv}
+            q[:, 0], k_new[:, 0], v_new[:, 0],
+            from_cache(read(cache["k"]), hd), from_cache(read(cache["v"]), hd),
+            pos, mesh=rules.mesh, seq_axes=kvseq_axes, batch_axes=batch_axes)
+        new = {"k": to_cache(ck), "v": to_cache(cv)}
+        cache = new if layer is None else {
+            n: jax.lax.dynamic_update_index_in_dim(cache[n], new[n], layer, 0)
+            for n in new}
         dt = jnp.dtype(cfg.compute_dtype)
         out = out32.astype(dt)[:, None]                       # (B,1,H,hd)
         res = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(dt))
         return constrain(res, "batch", None, None), cache
     if not cross:
         k_new, v_new = _project_kv(p, x, cfg, jnp.full((b, 1), pos))
-        cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, pos, 1),
-            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, pos, 1),
-        }
-        cache = {n: constrain(c, "batch", "kv_seq", "kv_heads", None)
+        rows = {"k": to_cache(k_new), "v": to_cache(v_new)}  # (1,KV,B,hdp)
+        if layer is None:
+            lead, at = (), (pos, 0, 0, 0)
+        else:
+            lead, at = (None,), (layer, pos, 0, 0, 0)
+            rows = {n: r[None] for n, r in rows.items()}
+        cache = {n: jax.lax.dynamic_update_slice(
+            cache[n], r.astype(cache[n].dtype), at) for n, r in rows.items()}
+        # hold the written cache in its stored (default) layout inside the
+        # program: left free, XLA relays a 2-row phi3 K stack out (rows
+        # before heads) at decode's entry and back at its exit
+        cache = {n: with_layout_constraint(c, Layout(tuple(range(c.ndim))))
                  for n, c in cache.items()}
-    k, v = cache["k"], cache["v"]
-    kv_len = k.shape[1]
+        cache = {n: constrain(c, *lead, "kv_seq", "kv_heads", "batch", None)
+                 for n, c in cache.items()}
+    k, v = read(cache["k"]), read(cache["v"])              # (S, KV, B, hdp)
 
     if cfg.attn_impl == "pallas":
         from ..kernels.decode_attention import ops as da_ops
-        out = da_ops.decode_attention(q[:, 0], k, v,
+        out = da_ops.decode_attention(q[:, 0], from_cache(k, hd),
+                                      from_cache(v, hd),
                                       length=None if cross else pos + 1,
                                       tuned=None)
     else:
-        scale = cfg.head_dim ** -0.5
-        kh = _repeat_kv(k, cfg.n_heads)
-        vh = _repeat_kv(v, cfg.n_heads)
+        kv_len, n_kv, _, hdp = k.shape
+        rep = cfg.n_heads // n_kv
+        if rep > 1:                     # (S, KV, B, hdp) -> (S, H, B, hdp)
+            k, v = (jnp.repeat(c, rep, axis=1) for c in (k, v))
         # bf16 operands + fp32 accumulation: never materialise an fp32
-        # copy of the cache.
-        qs = (q.astype(jnp.float32) * scale).astype(kh.dtype)
-        s = jnp.einsum("bqnh,bknh->bnqk", qs, kh,
+        # copy of the cache.  q is zero-padded to the cache's head size,
+        # so the padded lanes add nothing to the scores.
+        qs = (q[:, 0].astype(jnp.float32) * hd ** -0.5).astype(k.dtype)
+        qs = jnp.pad(qs, ((0, 0), (0, 0), (0, hdp - hd)))
+        s = jnp.einsum("bnh,snbh->bns", qs, k,
                        preferred_element_type=jnp.float32)
         if not cross:
-            valid = jnp.arange(kv_len)[None, None, None, :] <= pos
+            valid = jnp.arange(kv_len) <= pos
             s = jnp.where(valid, s, NEG_INF)
         w = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bnqk,bknh->bqnh", w.astype(vh.dtype), vh,
-                         preferred_element_type=jnp.float32)
-        out = out[:, 0]
+        out = jnp.einsum("bns,snbh->bnh", w.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)[..., :hd]
     out = out.astype(jnp.dtype(cfg.compute_dtype))[:, None]  # (B,1,H,hd)
     dt = jnp.dtype(cfg.compute_dtype)
     res = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(dt))
@@ -266,4 +326,4 @@ def decode_attention(p: Params, x: jax.Array, cache: Params,
 
 def precompute_cross_kv(p: Params, enc: jax.Array, cfg: ArchConfig) -> Params:
     k, v = _project_kv(p, enc, cfg, None)
-    return {"k": k, "v": v}
+    return {"k": to_cache(k), "v": to_cache(v)}

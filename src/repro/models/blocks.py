@@ -104,18 +104,29 @@ def prefill_layer(p: Params, x: jax.Array, cfg: ArchConfig, kind: str,
     return x + ch, state
 
 
-def decode_layer(p: Params, x: jax.Array, state: Params, cfg: ArchConfig,
-                 kind: str, is_moe: bool, pos: jax.Array
+def decode_layer(p: Params, x: jax.Array, stack: Params, g: jax.Array,
+                 cfg: ArchConfig, kind: str, is_moe: bool, pos: jax.Array
                  ) -> tuple[jax.Array, Params]:
-    """Single-token decode path. x: (B, 1, D)."""
+    """Single-token decode path. x: (B, 1, D).
+
+    ``stack`` is this slot's decode state for every group, stacked on a
+    leading axis; the layer's own is at index ``g``.  An attention layer
+    writes its new K/V row into the stack in place.  Mamba and RWKV
+    states have no slot axis and are small, so the layer's state is read
+    out and written back whole."""
     h = apply_norm(p["norm1"], x, cfg)
     if kind == "attn":
-        mixed, state = decode_attention(p["mixer"], h, state, cfg, pos=pos)
-    elif kind == "mamba":
-        mixed, state = decode_mamba(p["mixer"], h, state, cfg)
+        mixed, stack = decode_attention(p["mixer"], h, stack, cfg, pos=pos,
+                                        layer=g)
     else:
-        mixed, tstate = apply_rwkv_tmix(p["mixer"], h, cfg, state=state)
-        state = {**state, **tstate}
+        state = jax.tree.map(
+            lambda s: jax.lax.dynamic_index_in_dim(s, g, keepdims=False),
+            stack)
+        if kind == "mamba":
+            mixed, state = decode_mamba(p["mixer"], h, state, cfg)
+        else:
+            mixed, tstate = apply_rwkv_tmix(p["mixer"], h, cfg, state=state)
+            state = {**state, **tstate}
     x = x + mixed
     h = apply_norm(p["norm2"], x, cfg)
     if kind == "rwkv":
@@ -125,7 +136,9 @@ def decode_layer(p: Params, x: jax.Array, state: Params, cfg: ArchConfig,
         ch, _ = apply_moe(p["channel"], h, cfg)
     else:
         ch = apply_mlp(p["channel"], h, cfg)
-    return x + ch, state
+    if kind != "attn":
+        stack = write_state(stack, state, g)
+    return x + ch, stack
 
 
 # -- groups (smallest repeating pattern; the LM scans over these) -------------
@@ -154,13 +167,25 @@ def apply_group(p: Params, x: jax.Array, cfg: ArchConfig,
     return x, aux
 
 
-def prefill_group(p: Params, x: jax.Array, cfg: ArchConfig,
-                  positions: jax.Array) -> tuple[jax.Array, Params]:
-    states: Params = {}
+def write_state(stack: Params, state: Params, g: jax.Array) -> Params:
+    """One layer's decode ``state`` written in place into ``stack``, every
+    group's, at group ``g``.  A leaf shorter than the stack's fills its
+    leading part: a prompt's K/V fills the first slots of the cache."""
+    return jax.tree.map(
+        lambda s, n: jax.lax.dynamic_update_slice(
+            s, n[None].astype(s.dtype), (g,) + (0,) * n.ndim), stack, state)
+
+
+def prefill_group(p: Params, x: jax.Array, state: Params, g: jax.Array,
+                  cfg: ArchConfig, positions: jax.Array
+                  ) -> tuple[jax.Array, Params]:
+    """Prefill group ``g``, writing its layers' decode state into
+    ``state``, every group's, stacked."""
+    state = dict(state)
     for name, kind, is_moe in group_slots(cfg):
         x, s = prefill_layer(p[name], x, cfg, kind, is_moe, positions)
-        states[name] = s
-    return x, states
+        state[name] = write_state(state[name], s, g)
+    return x, state
 
 
 def init_group_state(cfg: ArchConfig, batch: int, max_len: int) -> Params:
@@ -168,10 +193,12 @@ def init_group_state(cfg: ArchConfig, batch: int, max_len: int) -> Params:
             for name, kind, _ in group_slots(cfg)}
 
 
-def decode_group(p: Params, x: jax.Array, state: Params, cfg: ArchConfig,
-                 pos: jax.Array) -> tuple[jax.Array, Params]:
-    new_state: Params = {}
+def decode_group(p: Params, x: jax.Array, state: Params, g: jax.Array,
+                 cfg: ArchConfig, pos: jax.Array) -> tuple[jax.Array, Params]:
+    """Decode group ``g``; ``state`` is every group's, stacked, and is
+    returned with group ``g``'s part advanced."""
+    state = dict(state)
     for name, kind, is_moe in group_slots(cfg):
-        x, s = decode_layer(p[name], x, state[name], cfg, kind, is_moe, pos)
-        new_state[name] = s
-    return x, new_state
+        x, state[name] = decode_layer(p[name], x, state[name], g, cfg, kind,
+                                      is_moe, pos)
+    return x, state

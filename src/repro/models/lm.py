@@ -137,54 +137,39 @@ class LM:
                 ) -> tuple[jax.Array, Params]:
         """Process a full prompt; returns (last-position logits, decode state).
 
-        Attention KV caches are padded to ``max_len`` capacity (defaults to
-        the prompt length) and sharded per the installed rules
-        ("prefill_kv_seq" maps the cache sequence dim).
+        The decode state is allocated at ``max_len`` capacity (defaults to
+        the prompt length) and carried through the layer loop: each layer
+        writes its state into the stack in place, attention its K/V into
+        the first slots, so no layer's state is stacked or padded after.
+        Attention KV caches are sharded per the installed rules ("kv_seq"
+        maps the cache sequence dim).
         """
         cfg = self.cfg
         batch = {"tokens": tokens, "labels": jnp.zeros_like(tokens)}
         if patch_embeds is not None:
             batch["patch_embeds"] = patch_embeds
         x, positions, _, _ = self.embed_inputs(params, batch)
-        s = x.shape[1]
-        max_len = max(max_len, s)
+        state = self.init_decode_state(x.shape[0], max(max_len, x.shape[1]))
 
-        def body(h, group_params):
-            h, state = prefill_group(group_params, h, cfg, positions)
-            return h, state
+        def body(carry, scanned):
+            h, state = carry
+            group_params, g = scanned
+            return prefill_group(group_params, h, state, g, cfg,
+                                 positions), None
 
-        x, states = jax.lax.scan(body, x, params["layers"])
+        (x, state), _ = jax.lax.scan(
+            body, (x, state), (params["layers"], jnp.arange(cfg.n_groups)))
         x = apply_norm(params["final_norm"], x, cfg)
-
-        # pad attention kv caches (G, B, S, KV, hd) -> (G, B, max_len, KV, hd)
-        def pad_kv(tree):
-            def visit(d):
-                out = {}
-                for k, v in d.items():
-                    if isinstance(v, dict):
-                        out[k] = visit(v)
-                    else:
-                        out[k] = v
-                if set(out) == {"k", "v"}:
-                    pad = max_len - out["k"].shape[2]
-                    if pad > 0:
-                        out = {kk: jnp.pad(vv, ((0, 0), (0, 0), (0, pad),
-                                                (0, 0), (0, 0)))
-                               for kk, vv in out.items()}
-                    out = {kk: constrain(vv, None, "batch", "kv_seq",
-                                         "kv_heads", None)
-                           for kk, vv in out.items()}
-                return out
-
-            return visit(tree)
-
-        states = pad_kv(states)
+        state = jax.tree_util.tree_map_with_path(
+            lambda path, c: constrain(c, None, "kv_seq", "kv_heads", "batch",
+                                      None)
+            if path[-1].key in ("k", "v") else c, state)
         head_w = (params["embed"]["tokens"].T if cfg.tie_embeddings
                   else params["embed"]["lm_head"])
         dtc = jnp.dtype(cfg.compute_dtype)
         last = x[:, -1:]
         logits = (last.astype(dtc) @ head_w.astype(dtc)).astype(jnp.float32)
-        return constrain(logits, "batch", None, "vocab"), states
+        return constrain(logits, "batch", None, "vocab"), state
 
     # -- decode ----------------------------------------------------------------
     def init_decode_state(self, batch: int, max_len: int) -> Params:
@@ -193,25 +178,37 @@ class LM:
         def one(_):
             return init_group_state(cfg, batch, max_len)
 
-        # stack per-group states along a leading axis to scan over
+        # stack per-group states along a leading axis, carried through
+        # the layer loop and indexed by group
         return jax.vmap(one)(jnp.arange(cfg.n_groups))
 
     def decode_step(self, params: Params, state: Params, tokens: jax.Array,
                     pos: jax.Array) -> tuple[jax.Array, Params]:
-        """tokens: (B, 1) -> (logits (B, 1, V), new_state)."""
+        """tokens: (B, 1) -> (logits (B, 1, V), new_state).
+
+        The stacked state is carried through the layer loop, not scanned
+        over and rebuilt: each attention layer writes its new K/V row into
+        the stack in place and reads its own layer from it, so a call
+        writes one row per layer and copies no cache.  The cache's own
+        layout, (S, KV, B, hd padded) per layer (``models/attention.py``),
+        is the one attention reads and XLA's default for the stored array,
+        so prefill emits it as decode reads it and decode, jitted with the
+        state donated, updates it in place.
+        """
         cfg = self.cfg
         x = embed_tokens(params["embed"], tokens, cfg)
 
-        def body(h, scanned):
-            group_params, group_state = scanned
-            h, new_state = decode_group(group_params, h, group_state, cfg, pos)
-            return h, new_state
+        def body(carry, scanned):
+            h, state = carry
+            group_params, g = scanned
+            return decode_group(group_params, h, state, g, cfg, pos), None
 
-        x, new_state = jax.lax.scan(body, x, (params["layers"], state))
+        (x, state), _ = jax.lax.scan(
+            body, (x, state), (params["layers"], jnp.arange(cfg.n_groups)))
         x = apply_norm(params["final_norm"], x, cfg)
         head_w = (params["embed"]["tokens"].T if cfg.tie_embeddings
                   else params["embed"]["lm_head"])
         dtc = jnp.dtype(cfg.compute_dtype)
         logits = (x.astype(dtc) @ head_w.astype(dtc)).astype(jnp.float32)
         logits = constrain(logits, "batch", None, "vocab")
-        return logits, new_state
+        return logits, state

@@ -13,6 +13,8 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.core.hetero import DeviceGroup
+from repro.launch.serve import _stream_step_builder
 from repro.models import build_model
 from repro.models.layers import init_mlp, apply_mlp
 from repro.models.moe import apply_moe, init_moe
@@ -81,12 +83,20 @@ def test_arch_decode_step_shapes(name):
     assert jax.tree.structure(state) == jax.tree.structure(state2)
 
 
-@pytest.mark.parametrize("name", ["qwen2.5-3b", "rwkv6-1.6b",
-                                  "jamba-v0.1-52b", "phi3.5-moe-42b-a6.6b"])
-def test_prefill_then_decode_matches_forward(name):
-    """logits(prefill(x[:n]) -> decode x[n]) == teacher-forced forward.
+@pytest.mark.parametrize("name,served", [
+    pytest.param(name, False, id=name)
+    for name in ("qwen2.5-3b", "phi3-mini-3.8b", "rwkv6-1.6b",
+                 "jamba-v0.1-52b", "phi3.5-moe-42b-a6.6b")
+] + [pytest.param("phi3-mini-3.8b", True, id="phi3-mini-3.8b-served")])
+def test_prefill_then_decode_matches_forward(name, served):
+    """logits(prefill(x[:n]) -> decode x[n], x[n+1], ...) == the
+    teacher-forced forward at every position, so each decode step's
+    carried write of its cache row (or recurrent state) is checked.
 
-    MoE capacity is raised so no token drops: capacity-based routing
+    ``served`` runs the serving step builder's own prefill and donated
+    decode on a chunk, greedily, and checks its tokens and prefill
+    logits against the forward over the prompt and what it served.  MoE
+    capacity is raised so no token drops: capacity-based routing
     legitimately differs between a full pass (overflow drops) and
     single-token decode (never overflows) — the standard train/serve
     asymmetry, not a bug."""
@@ -98,9 +108,18 @@ def test_prefill_then_decode_matches_forward(name):
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
     model = build_model(cfg)
     params = model.init(KEY)
-    b, t = 2, 16
+    b, t, steps = 2, 16, 4
     batch = _batch_for(cfg, b=b, t=t)
     tokens = batch["tokens"]
+    n = t - steps
+    if served:
+        group = DeviceGroup("all", jax.devices()[:1])
+        fn = _stream_step_builder(model, prompt_len=n, gen=steps + 1,
+                                  seed=0)(group)
+        out = fn({"tokens": np.asarray(tokens[:, :n])})
+        got = np.asarray(out["tokens"])
+        tokens = jnp.concatenate([tokens[:, :n], got[:, :-1]], axis=1)
+        batch = {**batch, "tokens": tokens}
 
     # teacher-forced logits for every position via loss-path backbone
     x, positions, _, _ = model.embed_inputs(params, batch)
@@ -108,17 +127,25 @@ def test_prefill_then_decode_matches_forward(name):
     head = (params["embed"]["tokens"].T if cfg.tie_embeddings
             else params["embed"]["lm_head"])
     full_logits = h.astype(jnp.float32) @ head.astype(jnp.float32)
+    if served:
+        np.testing.assert_allclose(np.asarray(out["logits"]),
+                                   np.asarray(full_logits[:, n - 1]),
+                                   atol=2e-3, rtol=2e-3)
+        np.testing.assert_array_equal(
+            got, np.asarray(jnp.argmax(full_logits[:, n - 1:], axis=-1)))
+        return
 
-    logits_p, state = model.prefill(params, tokens[:, :t - 1],
-                                    max_len=t + 4)
+    logits_p, state = model.prefill(params, tokens[:, :n], max_len=t + 4)
     np.testing.assert_allclose(np.asarray(logits_p[:, 0]),
-                               np.asarray(full_logits[:, t - 2]),
+                               np.asarray(full_logits[:, n - 1]),
                                atol=2e-3, rtol=2e-3)
-    logits_d, _ = model.decode_step(params, state, tokens[:, t - 1:t],
-                                    jnp.int32(t - 1))
-    np.testing.assert_allclose(np.asarray(logits_d[:, 0]),
-                               np.asarray(full_logits[:, t - 1]),
-                               atol=2e-3, rtol=2e-3)
+    for i in range(n, t):
+        logits_d, state = model.decode_step(params, state, tokens[:, i:i + 1],
+                                            jnp.int32(i))
+        np.testing.assert_allclose(np.asarray(logits_d[:, 0]),
+                                   np.asarray(full_logits[:, i]),
+                                   atol=2e-3, rtol=2e-3,
+                                   err_msg=f"decode at position {i}")
 
 
 def test_moe_matches_dense_mlp_when_single_expert():

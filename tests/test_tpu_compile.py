@@ -15,6 +15,7 @@ because ``jax.default_backend()`` is the CPU here.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -31,7 +32,9 @@ from repro.kernels.dna_automaton import ops as dna_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.mamba_scan import ops as ms_ops
 from repro.kernels.rwkv6_wkv import ops as wkv_ops
-from repro.launch.serve import serving_model
+from repro.launch.serve import compile_decode, serving_model
+from repro.models.attention import cache_head_dim
+from repro.obs import Observer
 
 HBM_BYTES = 15.75 * 2 ** 30          # what XLA lets one v5e program use
 
@@ -122,48 +125,100 @@ def test_kernel_compiles_for_v5e(one_chip, name, backward):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _on_chip(sharding, tree):
+    return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), tree)
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used <= HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
+    return m
+
+
+def _served(one_chip, arch, rows, prompt, gen, observer=None):
+    """The prefill and decode programs the step builder serves a chunk of
+    ``rows`` with, compiled for one v5e chip."""
+    model = serving_model(configs.get(arch))
+    rules = ShardingConfig(data_axes=("data",), model_axes=(), fsdp_axes=(),
+                           kv_shard="none", remat=False).rules(one_chip.mesh)
+    params = _on_chip(one_chip, jax.eval_shape(model.init,
+                                               jax.random.PRNGKey(0)))
+    tokens = _spec(one_chip, (rows, prompt), jnp.int32)
+
+    def prefill(p, t):
+        return model.prefill(p, t, max_len=prompt + gen)
+
+    with jax.set_mesh(one_chip.mesh), use_rules(rules):
+        state = _on_chip(one_chip, jax.eval_shape(prefill, params, tokens)[1])
+        decode = compile_decode(
+            jax.jit(model.decode_step, donate_argnums=(1,)), params, state,
+            _spec(one_chip, (rows, 1), jnp.int32),
+            _spec(one_chip, (), jnp.int32), observer)
+        return jax.jit(prefill).lower(params, tokens).compile(), decode
+
+
 def test_qwen_serving_step_fits_one_chip(one_chip):
     """qwen2.5-3b at full width with bf16 weights: the serving replica's
-    init, a 4 x 512 prefill into a 544-slot cache and the decode step
-    each compile for v5e and fit its memory."""
-    cfg = configs.get("qwen2.5-3b")
-    model = serving_model(cfg)
-    mesh = one_chip.mesh
-    rules = ShardingConfig(data_axes=("data",), model_axes=(), fsdp_axes=(),
-                           kv_shard="none", remat=False).rules(mesh)
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda x: _spec(one_chip, x.shape, x.dtype), tree)
-
-    def fits(compiled):
-        m = compiled.memory_analysis()
-        used = (m.argument_size_in_bytes + m.output_size_in_bytes
-                + m.temp_size_in_bytes - m.alias_size_in_bytes)
-        assert used <= HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
-        return m
-
+    init, a 4 x 512 prefill into a 544-slot cache and the decode step,
+    as the step builder serves them, each compile for v5e and fit its
+    memory."""
+    model = serving_model(configs.get("qwen2.5-3b"))
     init = jax.jit(model.init, out_shardings=one_chip).lower(
         _spec(one_chip, (2,), jnp.uint32)).compile()
-    m = fits(init)
+    m = _fits(init)
     assert m.temp_size_in_bytes < 2 ** 28       # no f32 copy of the weights
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     dtypes = {x.dtype for x in jax.tree.leaves(params)}
     assert dtypes == {jnp.dtype(jnp.bfloat16)}
     assert sum(x.size for x in jax.tree.leaves(params)) * 2 > 6e9
 
-    prompt, gen = 512, 32
-    tokens = jax.ShapeDtypeStruct((4, prompt), jnp.int32)
+    prefill, decode = _served(one_chip, "qwen2.5-3b", 4, 512, 32)
+    _fits(prefill)
+    _fits(decode)
 
-    def prefill(p, t):
-        return model.prefill(p, t, max_len=prompt + gen)
 
-    with jax.set_mesh(mesh), use_rules(rules):
-        fits(jax.jit(prefill).lower(on_chip(params),
-                                    on_chip(tokens)).compile())
-        _, state = jax.eval_shape(prefill, params, tokens)
-        last = jax.ShapeDtypeStruct((4, 1), jnp.int32)
-        pos = jax.ShapeDtypeStruct((), jnp.int32)
-        fits(jax.jit(model.decode_step, donate_argnums=(1,)).lower(
-            on_chip(params), on_chip(state), on_chip(last),
-            on_chip(pos)).compile())
+# (arch, rows, prompt, gen): qwen at the shape above; phi3's 8- and 2-row
+# chunks of the docqa cell (1024 + 32), whose heads of 96 the cache pads to
+# 128 lanes so that its default layout is the one attention reads.
+SERVED = [("qwen2.5-3b", 4, 512, 32), ("phi3-mini-3.8b", 8, 1024, 32),
+          ("phi3-mini-3.8b", 2, 1024, 32)]
+
+
+@pytest.mark.parametrize("arch,rows,prompt,gen", SERVED)
+def test_served_decode_updates_cache_in_place(one_chip, arch, rows, prompt,
+                                              gen):
+    """The decode program as served writes one row per layer into the
+    donated cache and copies no layer of it: no copy, and no fusion but
+    the in-place row write, has a whole layer's or the whole stack's
+    cache shape; its temporaries are small; the cache comes out in the
+    format it went in, which is the one prefill emits, so no program
+    relays it out between calls."""
+    cfg = configs.get(arch)
+    obs = Observer()
+    prefill, decode = _served(one_chip, arch, rows, prompt, gen, obs)
+    m = _fits(decode)
+    _fits(prefill)
+    assert m.temp_size_in_bytes < 64 * 2 ** 20, m.temp_size_in_bytes
+    assert obs.metrics.gauges["decode.temp_bytes"].value == \
+        m.temp_size_in_bytes
+    fmt = decode.input_formats[0][1]
+    assert decode.output_formats[1] == fmt
+    assert prefill.output_formats[1] == fmt
+
+    hdp = cache_head_dim(cfg.head_dim)
+    dims = f"{prompt + gen},{cfg.n_kv_heads},{rows},{hdp}"
+    cache = re.compile(
+        rf"^\s*(ROOT )?%(\S+) = bf16\[(1,|{cfg.n_layers},)?{dims}\]"
+        rf"\S* (copy|fusion|dynamic-slice)\(")
+    fused, writes = False, 0
+    for line in decode.as_text().splitlines():
+        if re.match(r"^(ENTRY )?%\S+ \(", line):
+            fused = "fused" in line.split()[0 if line[0] == "%" else 1]
+        hit = cache.match(line)
+        if hit and not (hit[4] == "dynamic-slice" and fused):
+            assert hit[4] == "fusion" and "dynamic-update-slice" in hit[2], \
+                line[:200]
+            writes += 1
+    assert writes == 2                    # K's and V's row write
