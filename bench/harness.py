@@ -5,13 +5,20 @@ A cell names three data files, found by name:
 
 * ``bench/configs/<config>.json``: the model's widths as run (keys as in
   its published ``config.json``), the registry architecture it selects,
-  the deployment (device groups, batcher and row quantum) and the name of
-  its reference under ``bench/reference``;
+  the deployment (device groups, batcher and row quantum) and, under
+  ``reference``, the name of its architecture;
 * ``bench/traffic/<mix>.json``: prompt and output lengths, rows per
   request, request classes and their SLOs, and the arrival process;
 * ``bench/cells/<workload>.json``: the offered rate, how many served rows
   the check compares and the limits it holds them to, and where the
   traced stretch falls.
+
+Through its configuration's ``reference`` a cell names a fourth file by
+name, ``bench/models/<reference>.py``: the architecture, which maps the
+file's published keys to the program's ``ArchConfig`` (``arch_config``)
+and counts the operations and bytes of its calls (``counts``); the plain
+reference the check compares with is ``bench/reference/<reference>.py``.
+A new architecture brings both files and needs no change here.
 
 Each metric is a reader of its own, ``bench/metrics/<metric>.py``, with
 ``read(run) -> float | None``.
@@ -34,12 +41,12 @@ import math
 import shutil
 import sys
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
+from functools import cached_property
 from pathlib import Path
 
 import numpy as np
 
-from . import counts as counts_mod
 from . import trace as trace_mod
 from . import traffic as traffic_mod
 
@@ -65,6 +72,7 @@ def _module(kind: str, name: str):
         raise BenchError(f"missing benchmark file {path}")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # a dataclass looks its module up
     spec.loader.exec_module(mod)
     return mod
 
@@ -91,36 +99,29 @@ class Cell:
         def here(metrics):
             return tuple(m for m in metrics if name in m.get("workloads", [name]))
 
-        return cls(name=name, config=_load(ROOT / conf["file"]),
+        cell = cls(name=name, config=_load(ROOT / conf["file"]),
                    traffic=_load(BENCH / "traffic" / f"{w['traffic']}.json"),
                    settings=_load(BENCH / "cells" / f"{name}.json"),
                    chips=int(w["chips"]), end_to_end=here(spec["end_to_end"]),
                    per_layer=here(spec["per_layer"]))
+        cell.architecture       # a missing module is refused before JAX starts
+        return cell
 
+    @cached_property
+    def architecture(self):
+        """The configuration's architecture module,
+        ``bench/models/<reference>.py``, loaded once: ``arch_config(config,
+        positions)`` and ``counts(config)``."""
+        return _module("models", self.config["reference"])
 
-# the configuration file's keys (published names) -> the program's fields
-ARCH_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-               "num_hidden_layers": "n_layers",
-               "num_attention_heads": "n_heads",
-               "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-               "vocab_size": "vocab_size",
-               "tie_word_embeddings": "tie_embeddings",
-               "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-               "attention_bias": "qkv_bias", "torch_dtype": "compute_dtype"}
-
-
-def arch_config(config: dict):
-    """The program's ``ArchConfig`` for the configuration file: the
-    registry's architecture with every width the file states."""
-    from repro import configs
-    base = configs.get(config["arch"])
-    if base.family != "dense" or config["hidden_act"] != "silu" \
-            or base.mlp_type != "swiglu" or base.norm_type != "rmsnorm":
-        raise BenchError(f"{config['arch']}: not the dense SwiGLU decoder "
-                         "that the file and its reference describe")
-    fields = {f: config[k] for k, f in ARCH_FIELDS.items()}
-    return replace(base, layer_kinds=("attn",) * fields["n_layers"],
-                   attn_impl="auto", **fields)
+    def arch_config(self, positions: int):
+        """The program's ``ArchConfig`` for requests of ``positions``
+        tokens; the architecture refuses (``ValueError``) what the program
+        would not run as the file states."""
+        try:
+            return self.architecture.arch_config(self.config, positions)
+        except ValueError as e:
+            raise BenchError(str(e)) from None
 
 
 def peak_of(kind: str) -> dict:
@@ -246,7 +247,7 @@ class Run:
     warmup_s: float               # the program's warm-up steps, in setup_s
     compiles_in_window: int
     memory_peak_bytes: int
-    counts: counts_mod.Counts
+    counts: object                # the architecture's counts(config)
     peak: dict
     sample: dict | None           # prompts, served tokens, prefill logits
     trace: dict | None = None     # trace.compact(), or None
@@ -289,17 +290,12 @@ def serve(cell: Cell, *, seed: int, seconds: float, devices, t0: float,
                                     serving_model)
     from repro.serve import BatcherConfig
 
-    cfg = arch_config(cell.config)
     tr, dep, st = cell.traffic, cell.config["deployment"], cell.settings
     if tr["arrival"] != "poisson":
         raise BenchError(f"arrival process {tr['arrival']!r}: serve_requests "
                          "offers Poisson arrivals only")
     p, g = tr["prompt_len"], tr["gen"]
-    window_len = cell.config.get("sliding_window")
-    if window_len is not None and p + g > window_len:
-        raise BenchError(f"{p} + {g} positions pass the configuration's "
-                         f"sliding_window {window_len}, which the program "
-                         "does not apply")
+    cfg = cell.arch_config(positions=p + g)
     rate = float(st["rate_rps"])
     n = traffic_mod.n_requests(rate, seconds)
     groups = [DeviceGroup(f"group{i}", [devices[j] for j in idx])
@@ -382,7 +378,7 @@ def serve(cell: Cell, *, seed: int, seconds: float, devices, t0: float,
               steps=steps, setup_s=start - t0, warmup_s=warmup_s,
               compiles_in_window=sum(start <= t <= end for t in compiles.at),
               memory_peak_bytes=int(peak),
-              counts=counts_mod.Counts.from_config(cell.config),
+              counts=cell.architecture.counts(cell.config),
               peak=peak_of(used[0].device_kind) if used[0].platform == "tpu"
               else {}, sample=sample,
               traced=list(window.traced) if window else [],

@@ -1,4 +1,5 @@
-"""``bench/counts.py`` against flops and bytes worked out by hand.
+"""The dense decoder's counts (``bench/models/dense_decoder.py``) against
+flops and bytes worked out by hand, and ``bench/counts.py``'s least time.
 
 qwen2.5-3b: d 2048, 36 layers, 16 heads over 2 KV heads of 128, d_ff
 11008, vocabulary 151936, tied, q/k/v bias.  One layer's matrices:
@@ -18,10 +19,12 @@ import pytest
 
 from bench import counts, harness
 
+dense_decoder = harness._module("models", "dense_decoder")
+
 
 def _counts(config):
     path = harness.BENCH / "configs" / f"{config}.json"
-    return counts.Counts.from_config(json.loads(path.read_text()))
+    return dense_decoder.counts(json.loads(path.read_text()))
 
 
 def test_qwen_parameters_weights_and_cache():
@@ -69,3 +72,33 @@ def test_least_time_names_its_bound():
     peak = {"bf16_flops_per_s": 2e14, "hbm_bytes_per_s": 1e12}
     assert counts.least_time(4e14, 1e12, peak) == (2.0, "compute")
     assert counts.least_time(2e14, 3e12, peak) == (3.0, "memory")
+
+
+# What the dense decoder's counts read at the cells' own shapes, as the
+# class read when it lived in bench/counts.py: prefill of each chunk size
+# the cells run, the last decode step, one row served, and a chunk split
+# over three devices.
+AT_CELL_SHAPES = {
+    "qwen2.5-3b": (70, 215, (64, 128, 43), [
+        (24946539495424.0, 6375923712.0), (49893078990848.0, 6579970048.0),
+        (16760956223488.0, 6308971008.0)], [
+        (400329539584.0, 6883172352.0), (800659079168.0, 7594467328.0),
+        (268971409408.0, 6649778688.0)], 1721670238208.0,
+        (264206199466.66666, 6311053994.666667)),
+    "phi3-mini-3.8b": (1024, 32, (2, 4, 8), [
+        (15256520491008.0, 8263303680.0), (30513040982016.0, 9081449472.0),
+        (61026081964032.0, 10717741056.0)], [
+        (15719202816.0, 8275898880.0), (31438405632.0, 9106639872.0),
+        (62876811264.0, 10768121856.0)], 7871725043712.0,
+        (10463739904.0, 7983256576.0)),
+}
+
+
+@pytest.mark.parametrize("config", sorted(AT_CELL_SHAPES))
+def test_counts_at_the_cells_shapes_are_unchanged(config):
+    c = _counts(config)
+    p, g, rows, prefill, decode, row, split = AT_CELL_SHAPES[config]
+    assert [c.prefill(r, p) for r in rows] == prefill
+    assert [c.decode(r, p + g - 2) for r in rows] == decode
+    assert c.served_row_flops(p, g) == row
+    assert c.decode(rows[1] / 3, p) == pytest.approx(split, rel=1e-15)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from bench import harness
 from bench.reference import dense_decoder as ref
 
+arch = harness._module("models", "dense_decoder")
+
 SMALL = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
          "num_attention_heads": 4, "head_dim": 16, "vocab_size": 256}
 WORKLOADS = ["qwen2.5-3b", "phi3-mini-3.8b"]
@@ -38,7 +40,7 @@ def small_config(workload: str) -> dict:
 def test_weights_are_the_served_weights(workload):
     from repro.launch.serve import serving_model
     config = small_config(workload)
-    params = jax.jit(serving_model(harness.arch_config(config)).init)(
+    params = jax.jit(serving_model(arch.arch_config(config)).init)(
         jax.random.PRNGKey(SEED))
     m = ref._dims(config)
     k_emb, k_layers, _ = jax.random.split(jax.random.PRNGKey(SEED), 3)
@@ -65,7 +67,7 @@ def test_weights_are_the_served_weights(workload):
 def test_reference_logits_match_the_model_in_float32(workload):
     from repro.models import build_model
     config = small_config(workload)
-    cfg = replace(harness.arch_config(config), param_dtype="bfloat16",
+    cfg = replace(arch.arch_config(config), param_dtype="bfloat16",
                   compute_dtype="float32")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(SEED))

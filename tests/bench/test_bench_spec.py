@@ -45,6 +45,8 @@ def test_workload_resolves_every_file(workload):
         assert callable(harness._module("metrics", metric["name"]).read)
     ref = harness._module("reference", cell.config["reference"])
     assert callable(ref.readings)
+    arch = cell.architecture
+    assert callable(arch.arch_config) and callable(arch.counts)
     assert set(cell.settings["check"]["limits"]) == {"token_gap",
                                                      "prefill_logit_err"}
     assert cell.traffic["arrival"] == "poisson"
@@ -60,7 +62,7 @@ def test_configuration_file_states_what_the_program_runs(workload):
     from dataclasses import replace
     from repro import configs
     cell = harness.Cell.resolve(workload)
-    cfg = harness.arch_config(cell.config)
+    cfg = cell.architecture.arch_config(cell.config)
     base = configs.get(cell.config["arch"])
     assert cfg.norm_eps == cell.config["rms_norm_eps"]
     assert replace(cfg, norm_eps=base.norm_eps) == base

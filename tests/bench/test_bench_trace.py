@@ -19,7 +19,9 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from bench import counts, harness, trace
+from bench import harness, trace
+
+dense_decoder = harness._module("models", "dense_decoder")
 
 FIXTURE = Path(__file__).parent / "data" / "trace_qwen_chat_v5e.json.gz"
 WINDOW_NS, BUSY_NS = 183_623_922, 183_369_762
@@ -42,7 +44,7 @@ def run(tr):
     return SimpleNamespace(
         trace=tr, traced=[(32, 1)], trace_mark_perf=mark,
         cell=SimpleNamespace(traffic={"prompt_len": 128, "gen": 4}),
-        counts=counts.Counts.from_config(config),
+        counts=dense_decoder.counts(config),
         peak=harness.peak_of("TPU v5 lite"),
         steps=[{"row_done_at": np.array([mark + WINDOW_NS / 1e9]),
                 "t_step": WINDOW_NS / 1e9}])
