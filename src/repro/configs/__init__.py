@@ -8,9 +8,9 @@ from __future__ import annotations
 
 from ..models.config import ArchConfig
 
-from . import (internvl2_76b, jamba_v0p1_52b, nemotron4_340b, phi3_mini_3p8b,
-               phi3p5_moe_42b, phi4_mini_3p8b, qwen2_moe_a2p7b, qwen2p5_3b,
-               rwkv6_1p6b, whisper_base)
+from . import (internvl2_76b, jamba2_3b, jamba_v0p1_52b, nemotron4_340b,
+               phi3_mini_3p8b, phi3p5_moe_42b, phi4_mini_3p8b, qwen2_moe_a2p7b,
+               qwen2p5_3b, rwkv6_1p6b, whisper_base)
 
 _MODULES = {
     "rwkv6-1.6b": rwkv6_1p6b,
@@ -22,6 +22,7 @@ _MODULES = {
     "qwen2-moe-a2.7b": qwen2_moe_a2p7b,
     "phi3.5-moe-42b-a6.6b": phi3p5_moe_42b,
     "jamba-v0.1-52b": jamba_v0p1_52b,
+    "jamba2-3b": jamba2_3b,
     "whisper-base": whisper_base,
 }
 

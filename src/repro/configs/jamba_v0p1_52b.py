@@ -3,7 +3,8 @@
 [arXiv:2403.19887; hf]  32L d_model=4096 32H (GQA kv=8) d_ff=14336
 vocab=65536; attention at layer index 4 of each 8-layer block
 (attn_layer_period=8, offset=4); MoE every other layer (period=2,
-offset=1); mamba d_state=16 d_conv=4 expand=2, dt_rank=256.
+offset=1); mamba d_state=16 d_conv=4 expand=2, dt_rank=256, with
+RMSNorms on dt, B and C after x_proj (HF JambaMambaMixer).
 
 No positional embeddings (the Mamba layers carry position information).
 """

@@ -2,12 +2,17 @@
 
 Forward — two grid programs behind one entry point:
 
-  * **serial** (``lanes=0``): grid (B, dI/bd, T/L); the (bd, S) state is
-    VMEM scratch carried across the innermost time-chunk dimension and
-    each cell steps its L tokens sequentially.  This is the CUDA
+  * **serial** (``lanes=0``): grid (B, dI/bd, T/L); the state is VMEM
+    scratch carried across the innermost time-chunk dimension and each
+    cell steps its L tokens sequentially.  This is the CUDA
     selective-scan kernel's strategy mapped onto the TPU memory
     hierarchy: discretised tensors (exp(delta A) etc.) are
-    rematerialised per timestep in VREGs and never touch HBM.
+    rematerialised per timestep in VREGs and never touch HBM.  The
+    state is held as (S, bd), channels in the vector lanes: d_state is
+    16, so a (bd, S) state would fill 16 of 128 lanes and need each
+    token's x, dt and y moved between lanes and sublanes.  B and C come
+    transposed, (S, L) per cell with time in lanes, and each token's
+    column is picked out by a lane mask.
   * **chunked** (``lanes>=2``): each cell owns a *span* of
     ``lanes * chunk`` tokens split into ``lanes`` chunks scanned in
     lockstep — the per-token loop runs ``chunk`` iterations with a
@@ -46,33 +51,41 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import grid_compiler_params, largest_aligned_divisor
 
 
-def _serial_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
-                   y_ref, h_out_ref, h_ref, *, chunk, n_chunks):
+def _serial_kernel(x_ref, dt_ref, at_ref, bt_ref, ct_ref, d_ref, h0_ref,
+                   y_ref, h_out_ref, h_ref, *, chunk, n_chunks, unroll):
     jc = pl.program_id(2)
 
     @pl.when(jc == 0)
     def _init():
         h_ref[...] = h0_ref[0]
 
-    a = a_ref[...]                                # (bd, S)
-    d = d_ref[0]                                  # (bd,)
+    at = at_ref[...]                              # (S, bd)
+    d = d_ref[...]                                # (1, bd)
+    bs, cs = bt_ref[0], ct_ref[0]                 # (S, chunk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bs.shape, 1)
 
-    def step(t, _):
-        x_t = x_ref[0, t]                         # (bd,)
-        dt_t = dt_ref[0, t]                       # (bd,)
-        b_t = b_ref[0, t]                         # (S,)
-        c_t = c_ref[0, t]                         # (S,)
-        da = jnp.exp(dt_t[:, None] * a)           # (bd, S)
-        h = da * h_ref[...] + (dt_t * x_t)[:, None] * b_t[None, :]
-        h_ref[...] = h
-        y_ref[0, t] = (h * c_t[None, :]).sum(axis=1) + d * x_t
-        return ()
+    def step(t, h):
+        x_t = x_ref[0, pl.ds(t, 1), :]            # (1, bd)
+        dt_t = dt_ref[0, pl.ds(t, 1), :]
+        now = lane == t
+        b_t = jnp.sum(jnp.where(now, bs, 0.0), axis=1, keepdims=True)
+        c_t = jnp.sum(jnp.where(now, cs, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dt_t * at) * h + b_t * (dt_t * x_t)        # (S, bd)
+        y_ref[0, pl.ds(t, 1), :] = (jnp.sum(h * c_t, axis=0, keepdims=True)
+                                    + d * x_t)
+        return h
 
-    jax.lax.fori_loop(0, chunk, step, ())
+    def steps(i, h):                  # Mosaic unrolls a loop fully or not
+        for k in range(unroll):
+            h = step(i * unroll + k, h)
+        return h
+
+    h = jax.lax.fori_loop(0, chunk // unroll, steps, h_ref[...])
+    h_ref[...] = h
 
     @pl.when(jc == n_chunks - 1)
     def _final():
-        h_out_ref[0] = h_ref[...]
+        h_out_ref[0] = h
 
 
 def _chunked_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
@@ -127,6 +140,14 @@ def _chunked_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     y_ref[0] = y.reshape(lanes * chunk, bd)
 
 
+def serial_chunk(t: int, chunk: int) -> int:
+    """The serial program's tokens per cell: ``chunk`` rounded up to a
+    multiple of 128 (time is the lane axis of its B and C blocks), and
+    no more than ``t`` rounded up likewise.  The scan pads time to a
+    whole number of such chunks."""
+    return min(-(-max(chunk, 1) // 128), -(-t // 128)) * 128
+
+
 def _clamp_chunking(t: int, chunk: int, lanes: int) -> tuple[int, int]:
     """Clamp (chunk, lanes) so ``chunk * lanes`` divides ``t``; lanes < 2
     collapses to the serial path (the ``lanes=0`` sentinel)."""
@@ -142,32 +163,33 @@ def selective_scan_kernel(x, delta, a, b, c, d, h0, *, block_d: int = 256,
     """x/delta: (B,T,dI) f32; a: (dI,S); b/c: (B,T,S); d: (dI,);
     h0: (B,dI,S).  Returns (y (B,T,dI) f32, h_T (B,dI,S) f32).
 
-    ``lanes=0`` runs the serial per-token scan; ``lanes>=2`` runs the
-    chunked formulation with ``lanes`` chunks of ``chunk`` tokens per
-    grid cell (clamped to divide T).
+    ``lanes=0`` runs the serial per-token scan over chunks of
+    ``serial_chunk(T, chunk)`` tokens, ``unroll`` of them a loop
+    iteration (``unroll`` must divide the chunk), any T; ``lanes>=2``
+    runs the chunked formulation with ``lanes`` chunks of ``chunk`` tokens
+    per grid cell (clamped to divide T).
     """
     bt, t, di = x.shape
     s = a.shape[1]
     block_d = largest_aligned_divisor(di, block_d, align=128)
+    unroll = max(int(unroll), 1)
+    if lanes < 2:
+        chunk = serial_chunk(t, chunk)
+        if chunk % unroll:
+            raise ValueError(f"unroll={unroll} does not divide the serial "
+                             f"program's chunk of {chunk} tokens")
+        return _serial_scan(x, delta, a, b, c, d, h0, block_d=block_d,
+                            chunk=chunk, unroll=unroll, dims=dims,
+                            interpret=interpret)
     chunk, lanes = _clamp_chunking(t, chunk, lanes)
-    span = chunk * lanes if lanes else chunk
+    span = chunk * lanes
     n_spans = t // span
     xspec = pl.BlockSpec((1, span, block_d), lambda b_, i, j: (b_, j, i))
     sspec = pl.BlockSpec((1, span, s), lambda b_, i, j: (b_, j, 0))
     hspec = pl.BlockSpec((1, block_d, s), lambda b_, i, j: (b_, i, 0))
-    if lanes:
-        kernel = functools.partial(_chunked_kernel, lanes=lanes, chunk=chunk,
-                                   unroll=max(int(unroll), 1),
-                                   n_spans=n_spans)
-        scratch = [pltpu.VMEM((block_d, s), jnp.float32),
-                   pltpu.VMEM((lanes, chunk, block_d, s), jnp.float32),
-                   pltpu.VMEM((lanes, chunk, block_d, s), jnp.float32)]
-    else:
-        kernel = functools.partial(_serial_kernel, chunk=chunk,
-                                   n_chunks=n_spans)
-        scratch = [pltpu.VMEM((block_d, s), jnp.float32)]
     return pl.pallas_call(
-        kernel,
+        functools.partial(_chunked_kernel, lanes=lanes, chunk=chunk,
+                          unroll=unroll, n_spans=n_spans),
         grid=(bt, di // block_d, n_spans),
         in_specs=[
             xspec, xspec,
@@ -181,10 +203,53 @@ def selective_scan_kernel(x, delta, a, b, c, d, h0, *, block_d: int = 256,
             jax.ShapeDtypeStruct((bt, t, di), jnp.float32),
             jax.ShapeDtypeStruct((bt, di, s), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((block_d, s), jnp.float32),
+                        pltpu.VMEM((lanes, chunk, block_d, s), jnp.float32),
+                        pltpu.VMEM((lanes, chunk, block_d, s), jnp.float32)],
         compiler_params=grid_compiler_params(dims, 2, 1),
         interpret=interpret,
+        name="mamba_scan",
     )(x, delta, a, b, c, d.reshape(1, di), h0)
+
+
+def _serial_scan(x, delta, a, b, c, d, h0, *, block_d, chunk, unroll, dims,
+                 interpret):
+    """The serial grid program, on the state's transposed (S, dI) layout.
+    Time is padded to whole chunks with delta 0, which leaves the state
+    as it is, and the padded outputs are dropped."""
+    bt, t, di = x.shape
+    s = a.shape[1]
+    pad = -t % chunk
+    if pad:
+        x, delta, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                          for v in (x, delta, b, c))
+    n_chunks = (t + pad) // chunk
+    xspec = pl.BlockSpec((1, chunk, block_d), lambda b_, i, j: (b_, j, i))
+    sspec = pl.BlockSpec((1, s, chunk), lambda b_, i, j: (b_, 0, j))
+    hspec = pl.BlockSpec((1, s, block_d), lambda b_, i, j: (b_, 0, i))
+    y, h_t = pl.pallas_call(
+        functools.partial(_serial_kernel, chunk=chunk, n_chunks=n_chunks,
+                          unroll=unroll),
+        grid=(bt, di // block_d, n_chunks),
+        in_specs=[
+            xspec, xspec,
+            pl.BlockSpec((s, block_d), lambda b_, i, j: (0, i)),
+            sspec, sspec,
+            pl.BlockSpec((1, block_d), lambda b_, i, j: (0, i)),
+            hspec,
+        ],
+        out_specs=[xspec, hspec],
+        out_shape=[
+            jax.ShapeDtypeStruct((bt, t + pad, di), jnp.float32),
+            jax.ShapeDtypeStruct((bt, s, di), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((s, block_d), jnp.float32)],
+        compiler_params=grid_compiler_params(dims, 2, 1),
+        interpret=interpret,
+        name="mamba_scan",
+    )(x, delta, a.T, b.swapaxes(1, 2), c.swapaxes(1, 2), d.reshape(1, di),
+      h0.swapaxes(1, 2))
+    return y[:, :t], h_t.swapaxes(1, 2)
 
 
 # -- backward: spans pre-pass + reverse adjoint sweep ---------------------------

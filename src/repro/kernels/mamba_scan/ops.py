@@ -25,6 +25,11 @@ from .kernel import selective_scan_bwd, selective_scan_kernel
 DEFAULTS = {"block_d": 256, "chunk": 64, "lanes": 0, "unroll": 1,
             "dims": "parallel"}
 BWD_DEFAULTS = {"block_d": 256, "chunk": 64, "dims": "parallel"}
+# The served prefill's launch (models/mamba.py on a TPU), chosen by a sweep
+# on a v5e chip at its shape, 8192 tokens of d_inner 5120 and d_state 16
+# (2 rows: 5.17 ms against DEFAULTS' 35.1 ms; docs/kernels.md).
+SERVED = {"block_d": 2560, "chunk": 128, "lanes": 0, "unroll": 8,
+          "dims": "parallel"}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))

@@ -154,16 +154,27 @@ def compile_decode(decode, params, state, tokens, pos, observer=None):
     program's temporary bytes, which a decode that copies its cache
     needs a cache's worth of, go to ``observer``'s metrics (gauge
     ``decode.temp_bytes``, the largest over the shapes compiled) and to
-    the log, once per chunk shape; nothing is recorded per call.
+    the log, once per chunk shape; nothing is recorded per call.  So do
+    the bytes one row of each kind of decode state holds at this shape
+    (gauges ``decode.state_bytes.kv``, attention's K/V cache, and
+    ``decode.state_bytes.ssm``, the recurrent layers' state).
     """
     compiled = decode.lower(params, state, tokens, pos).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
+    rows = tokens.shape[0]
+    held = {"kv": 0, "ssm": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+        kind = "kv" if path[-1].key in ("k", "v") else "ssm"
+        held[kind] += leaf.size * jnp.dtype(leaf.dtype).itemsize // rows
     obs = as_observer(observer)
     if obs is not None:
         gauge = obs.metrics.gauge("decode.temp_bytes")
         gauge.set(max(gauge.value or 0, temp))
-    log.info(f"decode program for {tokens.shape[0]} rows: {temp} "
-             "temporary bytes")
+        for kind, n in held.items():
+            obs.metrics.gauge(f"decode.state_bytes.{kind}").set(n)
+    log.info(f"decode program for {rows} rows: {temp} temporary bytes; "
+             f"state per row: {held['kv']} bytes of K/V, {held['ssm']} of "
+             "recurrent state")
     return compiled
 
 

@@ -174,7 +174,8 @@ class ArchConfig:
                      + dt_rank * d_in + d_in    # dt_proj (+bias)
                      + d_in * m.d_state         # A_log
                      + d_in                     # D
-                     + d_in * d)                # out_proj
+                     + d_in * d                 # out_proj
+                     + dt_rank + 2 * m.d_state)  # dt, B and C norms
                 out.append((f"mamba[{i}]", c))
             elif kind == "rwkv":
                 r = self.rwkv or RwkvConfig()

@@ -4,8 +4,15 @@ Training runs the selective scan as a ``lax.scan`` over time with an
 fp32 (B, d_inner, d_state) carry — the XLA reference the dry-run lowers.
 The Pallas kernel (``repro.kernels.mamba_scan``) fuses the same recurrence
 into VMEM for the TPU target and is validated against ``ref.py`` which
-mirrors this math.  Decode keeps a (conv window, ssm state) pair per layer
-and advances one token in O(d_inner * d_state).
+mirrors this math.  Prefill picks by platform: on a TPU it runs the
+kernel at the launch parameters chosen for the served shape
+(``mamba_scan.ops.SERVED``), elsewhere the ``lax.scan``; ``attn_impl``
+"pallas" runs the kernel on every path.  Decode keeps a (conv window,
+ssm state) pair per layer and advances one token in O(d_inner * d_state).
+
+Jamba normalises dt, B and C after ``x_proj``: RMSNorms of unit initial
+scale and epsilon ``norm_eps`` (HF ``JambaMambaMixer``'s ``dt_layernorm``,
+``b_layernorm``, ``c_layernorm``).
 """
 
 from __future__ import annotations
@@ -16,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dist.api import constrain
+from ..kernels.mamba_scan import ops as ms_ops
 from .config import ArchConfig, MambaConfig
 from .layers import chunked_scan, dense_init
 
@@ -39,6 +47,9 @@ def init_mamba(key, cfg: ArchConfig) -> Params:
     dt_init = jnp.exp(u * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
     dt_bias = dt_init + jnp.log(-jnp.expm1(-dt_init))  # inverse softplus
     return {
+        "dt_norm": jnp.ones((dt_rank,), dt),
+        "b_norm": jnp.ones((d_state,), dt),
+        "c_norm": jnp.ones((d_state,), dt),
         "in_proj": dense_init(ks[1], (d, 2 * d_in), dt),
         "conv_w": (jax.random.normal(ks[2], (d_conv, d_in)) * d_conv ** -0.5
                    ).astype(dt),
@@ -64,12 +75,21 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return out + b
 
 
+def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis, in float32."""
+    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * inv * scale.astype(jnp.float32)
+
+
 def _ssm_inputs(p: Params, xc: jax.Array, cfg: ArchConfig):
     d_in, d_state, _, dt_rank = _dims(cfg)
     dtc = jnp.dtype(cfg.compute_dtype)
     dbc = xc.astype(dtc) @ p["x_proj"].astype(dtc)
     dt_r, b_ssm, c_ssm = jnp.split(
         dbc.astype(jnp.float32), [dt_rank, dt_rank + d_state], axis=-1)
+    dt_r = _rms(dt_r, p["dt_norm"], cfg.norm_eps)
+    b_ssm = _rms(b_ssm, p["b_norm"], cfg.norm_eps)
+    c_ssm = _rms(c_ssm, p["c_norm"], cfg.norm_eps)
     delta = jax.nn.softplus(
         dt_r @ p["dt_proj"].astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["A_log"])                       # (d_in, d_state)
@@ -78,9 +98,9 @@ def _ssm_inputs(p: Params, xc: jax.Array, cfg: ArchConfig):
 
 def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig,
                 return_state: bool = False):
-    """Full-sequence training path. x: (B, T, D)."""
+    """Full-sequence path: training, and with ``return_state`` prefill,
+    which also returns the decode state. x: (B, T, D)."""
     dtc = jnp.dtype(cfg.compute_dtype)
-    b, t, d = x.shape
     xz = x.astype(dtc) @ p["in_proj"].astype(dtc)
     xs, z = jnp.split(xz, 2, axis=-1)
     xs = constrain(xs, "batch", None, "mamba_ff")
@@ -88,24 +108,40 @@ def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig,
                                   p["conv_b"].astype(dtc)))
     delta, a, b_ssm, c_ssm = _ssm_inputs(p, xc, cfg)
     xf = xc.astype(jnp.float32)
+    args = (xf, delta, a, b_ssm, c_ssm, p["D"])
 
     if cfg.attn_impl == "pallas":
-        from ..kernels.mamba_scan import ops as ms_ops
         # tuned=None: cached best launch params when kernel tuning is
         # enabled (repro.tune.kernels.configure), defaults otherwise.
         # The op carries a Pallas custom_vjp, so jax.grad through this
         # path runs tuned forward AND backward kernels (the backward
         # resolves its own "mamba_scan_bwd" launch parameters).
-        y, h_final = ms_ops.selective_scan(
-            xf, delta, a, b_ssm, c_ssm, p["D"], tuned=None)
-        y = y.astype(dtc) * jax.nn.silu(z)
-        out = y @ p["out_proj"].astype(dtc)
-        out = constrain(out, "batch", None, None)
-        if return_state:
-            d_conv = p["conv_w"].shape[0]
-            return out, {"conv": xs[:, -(d_conv - 1):], "ssm": h_final}
-        return out
+        y, h_final = ms_ops.selective_scan(*args, tuned=None)
+        seq = None
+    elif return_state:
+        # prefill: the chip's kernel on a TPU, the lax.scan elsewhere
+        y, h_final = jax.lax.platform_dependent(
+            *args, tpu=_served_scan, default=_xla_scan)
+        seq = "seq"
+    else:
+        y, h_final = _xla_scan(*args)
+        seq = "seq"
+    y = y.astype(dtc) * jax.nn.silu(z)
+    out = y @ p["out_proj"].astype(dtc)
+    out = constrain(out, "batch", seq, None)
+    if return_state:
+        d_conv = p["conv_w"].shape[0]
+        return out, {"conv": xs[:, -(d_conv - 1):], "ssm": h_final}
+    return out
 
+
+def _served_scan(x, delta, a, b, c, d):
+    return ms_ops.selective_scan(x, delta, a, b, c, d, interpret=False,
+                                 **ms_ops.SERVED)
+
+
+def _xla_scan(x, delta, a, b, c, d):
+    """The selective scan as a ``lax.scan`` over time: (y, final state)."""
     # The (B,T,d_in,d_state) discretised tensors are never materialised:
     # each step builds its own slice, and the chunked scan bounds backward
     # residual memory (see layers.chunked_scan).
@@ -116,20 +152,13 @@ def apply_mamba(p: Params, x: jax.Array, cfg: ArchConfig,
         y = jnp.einsum("bds,bs->bd", h, c_t)
         return h, y
 
-    h0 = jnp.zeros((b, xs.shape[-1], a.shape[-1]), jnp.float32)
+    h0 = jnp.zeros((x.shape[0], x.shape[-1], a.shape[-1]), jnp.float32)
     h_final, ys = chunked_scan(
         step, h0,
-        (delta.swapaxes(0, 1), b_ssm.swapaxes(0, 1),
-         c_ssm.swapaxes(0, 1), xf.swapaxes(0, 1)),
+        (delta.swapaxes(0, 1), b.swapaxes(0, 1),
+         c.swapaxes(0, 1), x.swapaxes(0, 1)),
         chunk=64)
-    y = ys.swapaxes(0, 1) + xf * p["D"]
-    y = (y.astype(dtc) * jax.nn.silu(z))
-    out = y @ p["out_proj"].astype(dtc)
-    out = constrain(out, "batch", "seq", None)
-    if return_state:
-        d_conv = p["conv_w"].shape[0]
-        return out, {"conv": xs[:, -(d_conv - 1):], "ssm": h_final}
-    return out
+    return ys.swapaxes(0, 1) + x * d, h_final
 
 
 # -- decode -------------------------------------------------------------------

@@ -223,10 +223,13 @@ def _ms_validate(cfg, meta) -> str | None:
            or _tiled(meta["di"], bd, 128, "block_d"))
     if err:
         return err
-    if lanes == 0:           # serial grid program
-        return (_tiled(t, chunk, 8, "chunk")
-                or _vmem(_f32(3 * chunk * bd + 4 * bd * s + 2 * chunk * s
-                              + bd), _f32(bd * s)))
+    if lanes == 0:           # serial grid program, (S, bd) state
+        from ...kernels.mamba_scan.kernel import serial_chunk
+        chunk = serial_chunk(t, chunk)
+        if chunk % cfg["unroll"]:
+            return f"unroll={cfg['unroll']} does not divide chunk={chunk}"
+        return _vmem(_f32(3 * chunk * bd + 3 * bd * s + 2 * chunk * s + bd),
+                     _f32(bd * s))
     span = chunk * lanes
     if t % span:
         return f"span chunk*lanes={span} does not divide t={t}"

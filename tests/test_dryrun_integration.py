@@ -40,7 +40,7 @@ for name in configs.ARCH_NAMES:
         specs = shapes.batch_specs_for(cfg, cell)
         assert specs, (name, cell.name)
         n += 1
-assert n == 32, n
+assert n == 36, n
 print("SPECS_OK", n)
 """, devices=1)
-    assert "SPECS_OK 32" in out
+    assert "SPECS_OK 36" in out

@@ -223,6 +223,62 @@ def test_selective_scan(bt, t, di, s, block_d, chunk):
                                rtol=2e-4)
 
 
+def test_selective_scan_at_the_served_launch_matches_the_xla_scan():
+    """The launch the served prefill runs on a TPU (``ops.SERVED``), in
+    interpret mode, against the model's own ``lax.scan`` path: at a
+    shape where the launch's block, chunk and lanes tile the extents as
+    they do at the served 8192 x 5120 (two blocks, two time spans)."""
+    from repro.models.mamba import _xla_scan
+
+    p = ms_ops.SERVED
+    span = p["chunk"] * p["lanes"] if p["lanes"] else max(p["chunk"], 128)
+    bt, t, di, s = 2, 2 * span, 2 * p["block_d"], 16
+    x = _randn(bt, t, di)
+    delta = jnp.abs(_randn(bt, t, di, scale=0.1))
+    a = -(jnp.abs(_randn(di, s)) + 0.5)
+    b, c = _randn(bt, t, s), _randn(bt, t, s)
+    d = _randn(di)
+    y, h = ms_ops.selective_scan(x, delta, a, b, c, d, interpret=True, **p)
+    ye, he = _xla_scan(x, delta, a, b, c, d)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=2e-5,
+                               rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(he), atol=2e-5,
+                               rtol=2e-4)
+
+
+@pytest.mark.parametrize("t", [100, 136, 500])
+def test_selective_scan_at_the_served_launch_takes_any_length(t):
+    """Prompts that are no whole number of the served launch's chunks:
+    the serial program pads time, and every token's output and the final
+    state still equal the model's own ``lax.scan`` path."""
+    from repro.models.mamba import _xla_scan
+
+    p = ms_ops.SERVED
+    bt, di, s = 1, p["block_d"], 16
+    x = _randn(bt, t, di)
+    delta = jnp.abs(_randn(bt, t, di, scale=0.1))
+    a = -(jnp.abs(_randn(di, s)) + 0.5)
+    b, c = _randn(bt, t, s), _randn(bt, t, s)
+    d = _randn(di)
+    y, h = ms_ops.selective_scan(x, delta, a, b, c, d, interpret=True, **p)
+    ye, he = _xla_scan(x, delta, a, b, c, d)
+    assert y.shape == (bt, t, di)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ye), atol=2e-5,
+                               rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(he), atol=2e-5,
+                               rtol=2e-4)
+
+
+def test_selective_scan_refuses_an_unroll_that_does_not_divide_the_chunk():
+    bt, t, di, s = 1, 128, 128, 4
+    x = _randn(bt, t, di)
+    b = _randn(bt, t, s)
+    a = -(jnp.abs(_randn(di, s)) + 0.5)
+    with pytest.raises(ValueError, match="unroll=3"):
+        ms_ops.selective_scan(x, jnp.abs(x), a, b, b, _randn(di),
+                              unroll=3, interpret=True)
+
+
 def test_selective_scan_gradients():
     bt, t, di, s = 1, 16, 32, 4
     x = _randn(bt, t, di)

@@ -86,8 +86,9 @@ def test_arch_decode_step_shapes(name):
 @pytest.mark.parametrize("name,served", [
     pytest.param(name, False, id=name)
     for name in ("qwen2.5-3b", "phi3-mini-3.8b", "rwkv6-1.6b",
-                 "jamba-v0.1-52b", "phi3.5-moe-42b-a6.6b")
-] + [pytest.param("phi3-mini-3.8b", True, id="phi3-mini-3.8b-served")])
+                 "jamba-v0.1-52b", "phi3.5-moe-42b-a6.6b", "jamba2-3b")
+] + [pytest.param(name, True, id=f"{name}-served")
+     for name in ("phi3-mini-3.8b", "jamba2-3b")])
 def test_prefill_then_decode_matches_forward(name, served):
     """logits(prefill(x[:n]) -> decode x[n], x[n+1], ...) == the
     teacher-forced forward at every position, so each decode step's
@@ -196,6 +197,7 @@ def test_param_counts_match_published_sizes():
         "qwen2-moe-a2.7b": (13e9, 15e9),
         "phi3.5-moe-42b-a6.6b": (40e9, 43e9),
         "jamba-v0.1-52b": (50e9, 53e9),
+        "jamba2-3b": (2.9e9, 3.2e9),
         "whisper-base": (0.06e9, 0.09e9),
     }
     actives = {
@@ -209,6 +211,25 @@ def test_param_counts_match_published_sizes():
     for name, (lo, hi) in actives.items():
         n = configs.get(name).active_param_count()
         assert lo <= n <= hi, f"{name} active: {n/1e9:.2f}B"
+
+
+def test_mamba_inner_norms_add_their_parameters():
+    """A Jamba Mamba layer by hand: in_proj, conv, x_proj, dt_proj and
+    its bias, A_log, D, out_proj, and the RMSNorms on dt, B and C
+    (dt_rank + 2 * d_state scales)."""
+    for name, d, dt_rank, norms in (("jamba-v0.1-52b", 4096, 256, 288),
+                                    ("jamba2-3b", 2560, 160, 192)):
+        cfg = configs.get(name)
+        d_in = 2 * d
+        layer = (d * 2 * d_in + 4 * d_in + d_in * (dt_rank + 32)
+                 + dt_rank * d_in + d_in + d_in * 16 + d_in + d_in * d)
+        i = cfg.layer_kinds.index("mamba")
+        assert dict(cfg.param_breakdown())[f"mamba[{i}]"] == layer + norms
+        params = jax.eval_shape(build_model(cfg.smoke()).init, KEY)
+        mixer = params["layers"]["slot0"]["mixer"]
+        assert {k: v.shape[1:] for k, v in mixer.items()
+                if k.endswith("_norm")} == {
+            "dt_norm": (16,), "b_norm": (8,), "c_norm": (8,)}
 
 
 def test_group_pattern_jamba():
@@ -225,4 +246,4 @@ def test_long_context_applicability():
     long = shapes.SHAPE_CELLS["long_500k"]
     runs = [n for n in configs.ARCH_NAMES
             if shapes.applicable(configs.get(n), long)[0]]
-    assert sorted(runs) == ["jamba-v0.1-52b", "rwkv6-1.6b"]
+    assert sorted(runs) == ["jamba-v0.1-52b", "jamba2-3b", "rwkv6-1.6b"]

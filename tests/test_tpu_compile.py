@@ -179,11 +179,37 @@ def test_qwen_serving_step_fits_one_chip(one_chip):
     _fits(decode)
 
 
+def test_jamba_serving_pair_fits_one_chip_and_prefill_scans_on_the_kernel(
+        one_chip):
+    """jamba2-3b's largest chunk of the longdoc cell (4 rows of 8192 + 32
+    positions), as the step builder serves it: both programs fit, the
+    prefill runs the Pallas selective scan by its name, the decode its
+    one-token XLA update, and the decode's compile records the bytes a
+    row of each kind of state holds."""
+    obs = Observer()
+    prefill, decode = _served(one_chip, "jamba2-3b", 4, 8192, 32, obs)
+    _fits(prefill)
+    _fits(decode)
+    calls = re.findall(r"%(mamba_scan(?:\.\d+)?) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", prefill.as_text())
+    assert calls, "no Pallas selective scan in the prefill"
+    assert "tpu_custom_call" not in decode.as_text()
+    gauges = obs.metrics.gauges
+    # K and V of 2 attention layers: 8224 slots of one 128-wide bf16 head
+    assert gauges["decode.state_bytes.kv"].value == 2 * 2 * 8224 * 128 * 2
+    # 26 Mamba layers: a 3-token bf16 conv window and an f32 16-state
+    # per channel of 5120
+    assert gauges["decode.state_bytes.ssm"].value == \
+        26 * (3 * 5120 * 2 + 5120 * 16 * 4)
+
+
 # (arch, rows, prompt, gen): qwen at the shape above; phi3's 8- and 2-row
 # chunks of the docqa cell (1024 + 32), whose heads of 96 the cache pads to
-# 128 lanes so that its default layout is the one attention reads.
+# 128 lanes so that its default layout is the one attention reads; jamba's
+# 4-row chunk of the longdoc cell (8192 + 32), its 2 attention layers one
+# per group of the stack.
 SERVED = [("qwen2.5-3b", 4, 512, 32), ("phi3-mini-3.8b", 8, 1024, 32),
-          ("phi3-mini-3.8b", 2, 1024, 32)]
+          ("phi3-mini-3.8b", 2, 1024, 32), ("jamba2-3b", 4, 8192, 32)]
 
 
 @pytest.mark.parametrize("arch,rows,prompt,gen", SERVED)
@@ -210,7 +236,7 @@ def test_served_decode_updates_cache_in_place(one_chip, arch, rows, prompt,
     hdp = cache_head_dim(cfg.head_dim)
     dims = f"{prompt + gen},{cfg.n_kv_heads},{rows},{hdp}"
     cache = re.compile(
-        rf"^\s*(ROOT )?%(\S+) = bf16\[(1,|{cfg.n_layers},)?{dims}\]"
+        rf"^\s*(ROOT )?%(\S+) = bf16\[(1,|{cfg.n_groups},)?{dims}\]"
         rf"\S* (copy|fusion|dynamic-slice)\(")
     fused, writes = False, 0
     for line in decode.as_text().splitlines():
